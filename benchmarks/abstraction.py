@@ -18,6 +18,7 @@ import numpy as np
 from repro.core import Block, Layer, Package, build
 from repro.core.materials import (COPPER, INTERPOSER, SILICON, UNDERFILL,
                                   Material, iso)
+from repro.runtime import use_compile_cache
 
 SOLDER = iso("solder", 57.0, 7400.0, 230.0)
 
@@ -164,6 +165,7 @@ def run_tables34(dx: float = 0.1e-3):
 
 
 def main(fast: bool = True):
+    use_compile_cache()
     rows = []
     t2 = run_table2(dx=12.5e-6 if fast else 6.25e-6)
     rows.append(("table2_ubump_drop_err_C", t2["drop_err_C"],

@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.core import build, package_from_name
 from repro.core.workloads import P2P5D, P3D, get_workload
+from repro.runtime import use_compile_cache
 
 T_VIOLATION = 85.0  # paper §5.4
 DT = 0.01
@@ -84,6 +85,7 @@ def main(argv=None):
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--out", default="benchmarks/artifacts/accuracy.json")
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.full:
         systems = ["2p5d_16", "2p5d_36", "2p5d_64", "3d_16x3"]
         workloads = ["WL1", "WL2", "WL3", "WL4", "WL5", "WL6"]

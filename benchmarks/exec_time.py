@@ -32,12 +32,12 @@ Measures, per system size and per registered fidelity:
     the dense tier (the node-count-independent headline) and max
     observation error vs the full-order exact-ZOH response in f64;
   * the ``sharded_dse`` section (PR 5): the family execution layer —
-    RC steady sweeps over meshes of {1, 2, 8} simulated host devices
-    (``mesh=`` on ``build_family``) and the B=10k chunk-streamed sweep
-    (``chunk_size=``), with speedup vs the single-device vmap path and
-    the sweep's own RSS high-water (peak minus post-setup RSS) as the
-    bounded-memory evidence. Each config runs in a subprocess so the
-    device-count flag can be set before jax initializes;
+    RC steady sweeps over meshes of {1, 2, 4, 8} devices, as many as
+    the process has (``mesh=`` on ``build_family``), and the B=10k
+    chunk-streamed sweep (``chunk_size=``), with speedup vs the
+    single-device vmap path and the sweep's growth of the process RSS
+    high-water as the bounded-memory evidence. Every config runs in
+    this one process, on submeshes of its devices;
   * the ``serving`` section (PR 7): the thermal-oracle service
     (``repro.serving``) — cold-vs-warm content-addressed model build
     time, warmed sequential p50/p99 latency for steady and ROM-transient
@@ -75,8 +75,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
-import sys
+import resource
 import time
 
 import jax
@@ -84,11 +83,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import PackageFamily, build, build_family, continuous_ss, \
-    discretize, discretize_rc, package_from_name, zoh_discretize
+    discretize, discretize_rc, make_2p5d_package, package_from_name, \
+    zoh_discretize
 from repro.core.assembly_ref import build_network_ref
 from repro.core.fidelity import SOLVER_CROSSOVER_NODES
 from repro.core.rc_model import build_network
 from repro.core.workloads import P2P5D, P3D, wl1
+from repro.runtime import use_compile_cache, x64
 
 SIM_FIDELITIES = ("rc", "dss", "rom", "hotspot", "3dice", "pact")
 
@@ -206,7 +207,7 @@ def bench_dse_sweep(system: str = "2p5d_16", n_candidates: int = 128)\
     must agree to <=1e-6 degC (recorded as ``match_max_err_degc``).
     """
     pkg, n_src, _ = _package(system)
-    with jax.experimental.enable_x64():
+    with x64():
         t0 = time.perf_counter()
         family = PackageFamily(pkg, params=("grid_offsets",))
         sim = build_family(family, "rc", dtype=jnp.float64)
@@ -272,7 +273,7 @@ def bench_dse_opt(system: str = "2p5d_16", sweep_b: int = 10000,
     from repro.kernels.fused_cg import adjoint
 
     pkg, n_src, _ = _package(system)
-    with jax.experimental.enable_x64():
+    with x64():
         family = PackageFamily(pkg, params=("grid_offsets",))
         model = RCFamilyModel(family, dtype=jnp.float64, solver="cg",
                               chunk_size=chunk)
@@ -533,110 +534,86 @@ def bench_rom(system: str, n_steps: int = 400) -> dict:
     return out
 
 
-# Each sharded_dse config runs in its OWN interpreter because the
-# simulated-device count (--xla_force_host_platform_device_count) must be
-# set before jax initializes — and because per-process peak RSS is the
-# honest bounded-memory metric for the chunk-streamed sweeps.
-_SHARDED_SCRIPT = r"""
-import json, os, resource, sys, time
-cfg = json.loads(sys.argv[1])
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                           + str(cfg["devices"]) + " "
-                           + os.environ.get("XLA_FLAGS", ""))
-import numpy as np
-import jax
-from repro.core import PackageFamily, build_family, make_2p5d_package
-
-pkg = make_2p5d_package(cfg["chips"])
-fam = PackageFamily(pkg, params=("grid_offsets",))
-# draw candidates in slices: sample_params validates host-side with
-# (B, E)-sized temporaries, and one B=10k draw would dominate the
-# process's RSS high-water mark — the metric meant to expose the SWEEP's
-# footprint, not setup's
-slice_b = min(cfg["b"], 1000)
-params = np.vstack([fam.sample_params(min(slice_b, cfg["b"] - s), seed=s)
-                    for s in range(0, cfg["b"], slice_b)])
-q = np.full((cfg["b"], cfg["chips"]), 3.0, np.float32)
-sim = build_family(fam, "rc",
-                   mesh=cfg["devices"] if cfg["devices"] > 1 else None,
-                   chunk_size=cfg["chunk"])
-
-def rss_mb():
+def _rss_mb() -> float:
+    """Process peak RSS so far (ru_maxrss is KiB on Linux)."""
     return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024.0
-
-def sweep():
-    th = sim.steady_state_batch(params, q)
-    return np.asarray(sim.observe_batch(th, params))
-
-setup_rss = rss_mb()
-t0 = time.perf_counter()
-temps = sweep()
-cold = time.perf_counter() - t0
-times = []
-for _ in range(cfg["reps"]):
-    t0 = time.perf_counter()
-    sweep()
-    times.append(time.perf_counter() - t0)
-print(json.dumps({
-    "devices": cfg["devices"], "b": cfg["b"], "chunk": cfg["chunk"],
-    "cold_s": cold, "warm_s": min(times),
-    "per_candidate_us": min(times) / cfg["b"] * 1e6,
-    "peak_temp_degc": float(temps.max()),
-    "setup_rss_mb": setup_rss,
-    "peak_rss_mb": rss_mb(),
-    "sweep_rss_mb": rss_mb() - setup_rss,  # the sweep's own high-water
-}))
-"""
 
 
 def _run_sharded_cfg(cfg: dict) -> dict:
-    env = dict(os.environ)
-    src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT,
-                          json.dumps(cfg)], env=env, capture_output=True,
-                         text=True, timeout=3600)
-    if out.returncode != 0:
-        raise RuntimeError(f"sharded_dse config {cfg} failed:\n"
-                           f"{out.stderr[-2000:]}")
-    return json.loads(out.stdout.strip().splitlines()[-1])
+    """One sharded_dse config in THIS process: the sweep runs on a mesh
+    over the first ``cfg["devices"]`` devices (``make_host_mesh`` builds
+    submeshes), so one process holds the chip(s) for every config.
+
+    ``sweep_rss_mb`` is how far the sweep raised the process's peak RSS;
+    ``bench_sharded_dse`` runs the streamed configs before the unchunked
+    baseline so each is measured against the setup's high-water mark.
+    """
+    fam = PackageFamily(make_2p5d_package(cfg["chips"]),
+                        params=("grid_offsets",))
+    # draw candidates in slices: sample_params validates host-side with
+    # (B, E)-sized temporaries, and one B=10k draw would dominate the
+    # high-water mark meant to expose the SWEEP's footprint
+    slice_b = min(cfg["b"], 1000)
+    params = np.vstack([fam.sample_params(min(slice_b, cfg["b"] - s),
+                                          seed=s)
+                        for s in range(0, cfg["b"], slice_b)])
+    q = np.full((cfg["b"], cfg["chips"]), 3.0, np.float32)
+    sim = build_family(fam, "rc",
+                       mesh=cfg["devices"] if cfg["devices"] > 1 else None,
+                       chunk_size=cfg["chunk"])
+
+    def sweep():
+        th = sim.steady_state_batch(params, q)
+        return np.asarray(sim.observe_batch(th, params))
+
+    setup_rss = _rss_mb()
+    t0 = time.perf_counter()
+    temps = sweep()
+    cold = time.perf_counter() - t0
+    warm = _host_time(sweep, reps=cfg["reps"])
+    return {"devices": cfg["devices"], "b": cfg["b"], "chunk": cfg["chunk"],
+            "cold_s": cold, "warm_s": warm,
+            "per_candidate_us": warm / cfg["b"] * 1e6,
+            "peak_temp_degc": float(temps.max()),
+            "setup_rss_mb": setup_rss, "peak_rss_mb": _rss_mb(),
+            "sweep_rss_mb": _rss_mb() - setup_rss}
 
 
 def bench_sharded_dse(system: str = "2p5d_16", b_scale: int = 2048,
                       b_stream: int = 10000, chunk: int = 512,
-                      device_counts=(1, 2, 8), reps: int = 3) -> dict:
+                      device_counts=(1, 2, 4, 8), reps: int = 3) -> dict:
     """Sharded family execution (PR 5 tentpole): the RC steady sweep over
-    a mesh of simulated host devices and through the chunk-streamed path.
+    a mesh of devices and through the chunk-streamed path.
 
-    Two sub-sections: ``scaling`` sweeps the device count at a fixed
-    mid-size B (speedup vs the single-device vmap path — on this
-    container's few cores the interesting signal is that sharding is
-    overhead-free, on real multi-chip hosts it is the scaling itself);
-    ``streamed`` runs the large-B sweep (default 10k candidates) through
-    ``chunk_size`` streaming, on one device and on the full mesh, plus
-    the same B unchunked as the memory baseline — the sweep's own RSS
-    high-water (``sweep_rss_mb``) shows the stream holding
-    B-independent memory.
+    Every config runs in this process, on the device counts in
+    ``device_counts`` that the process has (on the CPU, set
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before
+    starting it to get simulated devices). Two sub-sections:
+    ``scaling`` sweeps the device count at a fixed mid-size B (speedup
+    vs the single-device vmap path); ``streamed`` runs the large-B sweep
+    (default 10k candidates) through ``chunk_size`` streaming, on one
+    device and on the largest mesh, plus the same B unchunked as the
+    memory baseline (run last: ``sweep_rss_mb`` is high-water growth).
     """
     _, chips, _ = _package(system)  # 2.5D: one source per chiplet
+    counts = [d for d in device_counts if d <= len(jax.devices())]
     scaling = []
-    for d in device_counts:
+    for d in counts:
         r = _run_sharded_cfg({"devices": d, "b": b_scale, "chunk": None,
                               "chips": chips, "reps": reps})
         scaling.append(r)
         print(f"[sharded  ] {system:8s} B={b_scale:5d} devices={d} "
               f"warm={r['warm_s']:.3f}s rss={r['peak_rss_mb']:.0f}MB",
               flush=True)
-    base = next(r for r in scaling if r["devices"] == 1)["warm_s"]
+    base = scaling[0]["warm_s"]
     for r in scaling:
         r["speedup_vs_1dev"] = base / max(r["warm_s"], 1e-12)
 
     streamed = []
     stream_cfgs = [
-        {"devices": 1, "chunk": None},                 # vmap mem baseline
         {"devices": 1, "chunk": chunk},
-        {"devices": max(device_counts), "chunk": chunk},
+        {"devices": max(counts), "chunk": chunk},
+        {"devices": 1, "chunk": None},                 # vmap mem baseline
     ]
     for c in stream_cfgs:
         r = _run_sharded_cfg({**c, "b": b_stream, "chips": chips,
@@ -647,7 +624,7 @@ def bench_sharded_dse(system: str = "2p5d_16", b_scale: int = 2048,
               f"warm={r['warm_s']:.2f}s "
               f"sweep_rss={r['sweep_rss_mb']:.0f}MB",
               flush=True)
-    vmap_base = streamed[0]["warm_s"]
+    vmap_base = streamed[-1]["warm_s"]
     for r in streamed:
         r["speedup_vs_1dev_vmap"] = vmap_base / max(r["warm_s"], 1e-12)
     return {"system": system, "b_scale": b_scale, "b_stream": b_stream,
@@ -862,6 +839,7 @@ def main(argv=None):
                     help="candidate count for the dse_sweep section")
     ap.add_argument("--out", default="BENCH_exec_time.json")
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.smoke:
         sim_systems, n_steps = ["2p5d_16"], 200
         assembly_systems = ["2p5d_16"]

@@ -10,6 +10,8 @@ import time
 import jax
 import numpy as np
 
+from repro.runtime import use_compile_cache
+
 
 def bench_dss_kernel():
     """us/call of the DSS step kernel vs its oracle (N=640, paper's
@@ -41,6 +43,7 @@ def main(argv=None):
     ap.add_argument("--skip", default="",
                     help="comma list: abstraction,accuracy,exec,roofline")
     args = ap.parse_args(argv)
+    use_compile_cache()
     skip = set(args.skip.split(",")) if args.skip else set()
     extra = ["--full"] if args.full else []
 

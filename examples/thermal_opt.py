@@ -26,12 +26,12 @@ import time
 import numpy as np
 
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import PackageFamily, build_family, make_2p5d_package, \
     optimize_family
 from repro.core.rc_model import RCFamilyModel
 from repro.kernels.fused_cg import adjoint
+from repro.runtime import x64
 
 pkg = make_2p5d_package(16)
 family = PackageFamily(pkg, params=("grid_offsets",))
@@ -43,7 +43,7 @@ hot = [5, 6, 9, 10]
 q = np.full(16, IDLE)
 q[hot] = HOT
 
-with enable_x64():
+with x64():
     # -----------------------------------------------------------------
     # act 1: the brute-force baseline — 10k candidates, 10k solves
     # -----------------------------------------------------------------

@@ -11,7 +11,15 @@ string through the registry, at two levels:
   ``build(pkg, fidelity="rc"|"fvm"|"dss"|...)``   one concrete package
   ``build_family(PackageFamily(pkg, params=...))`` a whole design space,
       evaluated as a device batch axis (``BatchedThermalSimulator``).
+
+Matmul precision: a TPU's default rounds f32 matmul operands to bf16 (8
+mantissa bits), which puts f32 temperatures ~0.1 °C off and stalls f32
+CG. The models need f32 matmuls to be f32, so importing this package
+sets JAX's default matmul precision to "highest" unless the process has
+already chosen one. The Pallas kernels ask for it explicitly.
 """
+import jax
+
 from ..distribution.family_exec import FamilyExecutor
 from .assembly import NumericAssembly, SymbolicNetwork, symbolic_network
 from .baselines import BASELINES, hotspot_like, pact_like, threedice_like
@@ -75,3 +83,6 @@ __all__ = [
     "RoutedFamilySimulator", "RoutedThermalSimulator",
     "ALL_WORKLOADS", "P2P5D", "P3D", "PowerSpec", "get_workload",
 ]
+
+if jax.config.jax_default_matmul_precision is None:
+    jax.config.update("jax_default_matmul_precision", "highest")

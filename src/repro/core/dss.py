@@ -32,6 +32,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+import scipy.linalg
 
 from ..kernels.dss_step.ops import dss_rollout, dss_step
 from ..kernels.fused_cg.ops import all_finite, record_fallback
@@ -306,14 +307,8 @@ def build_dss(pkg: Package, ts: float = 0.01, cap_multipliers=None,
 
 
 def _expm(a: np.ndarray) -> np.ndarray:
-    """Scaling-and-squaring matrix exponential (host, float64).
-
-    Uses jax.scipy.linalg.expm under float64 to avoid a scipy dependency in
-    the hot path; small N makes this instantaneous.
-    """
-    with jax.experimental.enable_x64():
-        return np.asarray(
-            jax.scipy.linalg.expm(jnp.asarray(a, jnp.float64)))
+    """Scaling-and-squaring matrix exponential (host, float64)."""
+    return scipy.linalg.expm(np.asarray(a, np.float64))
 
 
 class EighZOH:

@@ -79,6 +79,16 @@ class RCNetwork:
         return np.bincount(self.rows, weights=self.gvals,
                            minlength=self.n) + self.gconv
 
+    def neg_g_sparse(self, shift: float = 0.0):
+        """``shift * diag(C) - G`` as a host f64 scipy CSC matrix: the
+        sparse operator behind the host factorizations and references."""
+        import scipy.sparse as sp
+        n = self.n
+        diag = sp.diags(self.neg_g_diag() + shift * self.C)
+        off = sp.coo_matrix((self.gvals, (self.rows, self.cols)),
+                            shape=(n, n))
+        return (diag - off).tocsc()
+
     def neg_g_matvec(self, x: np.ndarray) -> np.ndarray:
         """(-G) @ x on the host (f64, O(E n_cols)); x is (N,) or (N, k)."""
         x = np.asarray(x, np.float64)
@@ -353,7 +363,9 @@ class ThermalRCModel:
         Either closure records a :class:`CGStats` on itself as
         ``.last_stats`` after each concrete call (device iteration count,
         final relative residual, ``converged``) and warns host-side when
-        the solve hit the iteration cap.
+        the solve hit the iteration cap. ``.device_solve`` is the jitted
+        device CG solve ``rhs (N,) -> (x, CGStats)`` both closures run,
+        exposed so callers can lower it and inspect the program.
         """
         gvals, gdiag = self._gvals, self._gdiag
         dtype, backend, impl = self.dtype, self._backend, self.cg_impl
@@ -384,6 +396,7 @@ class ThermalRCModel:
                 return sol
 
             steady_plain.last_stats = None
+            steady_plain.device_solve = solve_dev
             return steady_plain
 
         # host float64 side: residuals via the network's O(E) COO matvec
@@ -418,6 +431,7 @@ class ThermalRCModel:
             return x
 
         steady.last_stats = None
+        steady.device_solve = solve_dev
         return steady
 
     def steady_state(self, q_src):
@@ -698,7 +712,7 @@ class RCFamilyModel:
     Construct with ``mesh=``/``chunk_size=`` (or a shared ``executor=``,
     as the DSS/ROM rungs do) to select the execution layout.
 
-    Use ``dtype=jnp.float64`` (inside ``jax.experimental.enable_x64()``)
+    Use ``dtype=jnp.float64`` (inside ``repro.runtime.x64()``)
     to validate against a per-candidate ``build()`` loop to <=1e-6 degC.
     """
 

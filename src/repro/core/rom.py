@@ -26,10 +26,9 @@ well conditioned and makes the reduced pencil symmetric.
 Basis construction is a one-time cost per package. On the ``"dense"``
 solver tier the inner solves ``(-G)^-1 B`` reuse one host Cholesky
 factorization; on the ``"cg"`` tier (``"auto"`` above the measured
-crossover) they run a matrix-free f64 block CG on the O(E)
-``kernels/coo_matvec`` segment-sum kernel — G is never materialized even
-at 8k+ nodes. The reduced system is then sampled with the SAME exact-ZOH
-discretization as the full-order DSS rung
+crossover) they reuse one host sparse LU of the O(E) COO operator — G
+is never materialized even at 8k+ nodes. The reduced system is then
+sampled with the SAME exact-ZOH discretization as the full-order DSS rung
 (:func:`~repro.core.dss.zoh_discretize`, fed the r x r pencil), so
 ``build(pkg, "rom")`` exposes the full ``ThermalSimulator`` protocol and
 drops into every DSS consumer, including the runtime
@@ -57,9 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.fused_cg.ops import (all_finite, fused_cg_plan,
-                                    fused_cg_solve, record_fallback,
-                                    warn_unconverged)
+from ..kernels.fused_cg.ops import all_finite, record_fallback
 from ..testing import faults
 from .dss import family_zoh_simulate, zoh_discretize
 from .fidelity import (evict_stale_jits, register_family_fidelity,
@@ -78,10 +75,7 @@ DEFAULT_MOMENTS = 6
 _DROP_TOL = 1e-8
 
 
-def _make_neg_g_solver(net: RCNetwork, solver: str,
-                       cg_tol: float = 1e-10, cg_maxiter: int = 5000,
-                       matvec_backend: str = "auto",
-                       cg_impl: str = "auto", shift: float = 0.0):
+def _make_neg_g_solver(net: RCNetwork, solver: str, shift: float = 0.0):
     """Block solver ``B (N, k) -> (shift*C - G)^-1 B`` in float64 (host
     in/out); ``shift=0`` is the plain ``(-G)^-1`` of the single-point
     Krylov recursion and of every steady-state consumer.
@@ -93,12 +87,9 @@ def _make_neg_g_solver(net: RCNetwork, solver: str,
     dual solves.
 
     "dense": one host Cholesky of (shift*C - G), reused for every block.
-    "cg": matrix-free block CG where each iteration over the whole block
-    is one fused Jacobi-PCG step (``kernels/fused_cg``; the block rides
-    the kernel's batch axis) — the dense G is never formed. Runs in f64
-    on device (the one-time construction wraps itself in ``enable_x64``;
-    runtime never needs it). ``cg_impl="unfused"`` is the historical
-    one-op-per-piece escape hatch.
+    "cg": one host sparse LU (``scipy.sparse.linalg.splu``) of the O(E)
+    COO operator, reused for every block — the dense G is never formed.
+    Host f64 math stays on the host: nothing here touches the device.
     """
     shift = float(shift)
     if shift < 0.0:
@@ -111,28 +102,15 @@ def _make_neg_g_solver(net: RCNetwork, solver: str,
         cho = sla.cho_factor(a)
         return lambda b: sla.cho_solve(cho, b)
 
-    neg_diag = net.neg_g_diag() + shift * net.C
-    with jax.experimental.enable_x64():
-        plan = fused_cg_plan(net.rows, net.cols, net.n)
-        gvals = jnp.asarray(net.gvals, jnp.float64)
-        diag = jnp.asarray(neg_diag, jnp.float64)
-
-        @jax.jit
-        def solve(rhs):  # (k, N) block on the fused kernel's batch axis
-            return fused_cg_solve(plan, diag, gvals, rhs,
-                                  tol=cg_tol, maxiter=cg_maxiter,
-                                  impl=cg_impl, backend=matvec_backend)
-
+    from scipy.sparse.linalg import splu
+    lu = splu(net.neg_g_sparse(shift))
     dense_fallback: list = []    # lazily built guardrail solver
 
     def solve_block(b):
-        with jax.experimental.enable_x64():
-            out, stats = solve(jnp.asarray(np.ascontiguousarray(b.T)))
-            warn_unconverged(stats, "rom basis block CG")
-            res = faults.corrupt("rom.basis_solve",
-                                 np.asarray(out, np.float64).T)
+        res = faults.corrupt("rom.basis_solve",
+                             lu.solve(np.asarray(b, np.float64)))
         if not np.isfinite(res).all():
-            # numerical guardrail: a poisoned CG output must not leak
+            # numerical guardrail: a poisoned sparse solve must not leak
             # into the Krylov basis — promote this block to the dense
             # Cholesky tier (built once, reused for later blocks)
             record_fallback("rom.basis_solve")
@@ -147,8 +125,7 @@ def _make_neg_g_solver(net: RCNetwork, solver: str,
 
 def krylov_basis(net: RCNetwork, r: Optional[int] = None,
                  n_moments=DEFAULT_MOMENTS, solver: str = "auto",
-                 drop_tol: float = _DROP_TOL, cg_tol: float = 1e-10,
-                 cg_maxiter: int = 5000, cg_impl: str = "auto",
+                 drop_tol: float = _DROP_TOL,
                  shifts: tuple = (0.0,)) -> np.ndarray:
     """C-orthonormal block-Krylov basis V (N, r) matching block moments
     of ``H (sC - G)^-1 P`` around the expansion points ``shifts``
@@ -209,9 +186,7 @@ def krylov_basis(net: RCNetwork, r: Optional[int] = None,
         m_j = moments[j]
         if m_j == 0 or v_basis.shape[1] >= r_cap:
             continue
-        solve_block = _make_neg_g_solver(net, solver, cg_tol=cg_tol,
-                                         cg_maxiter=cg_maxiter,
-                                         cg_impl=cg_impl, shift=s)
+        solve_block = _make_neg_g_solver(net, solver, shift=s)
         # single-shift explicit r keeps generating moments until the
         # budget fills; with several points each spends exactly its
         # moment count so later shifts see the leftover budget
@@ -494,8 +469,7 @@ def build_rom(pkg: Package, r: Optional[int] = None,
               solver: str = "auto", dtype=jnp.float32,
               cap_multipliers: Optional[dict] = None,
               basis: Optional[np.ndarray] = None,
-              cg_tol: float = 1e-10, cg_maxiter: int = 5000,
-              cg_impl: str = "auto", shifts: tuple = (0.0,),
+              shifts: tuple = (0.0,),
               grid: Optional[NodeGrid] = None) -> ROMModel:
     """Registry builder: package -> RC network -> Krylov basis -> ROM.
 
@@ -512,8 +486,7 @@ def build_rom(pkg: Package, r: Optional[int] = None,
                             pkg, cap_multipliers))
     if basis is None:
         basis = krylov_basis(net, r=r, n_moments=n_moments, solver=solver,
-                             cg_tol=cg_tol, cg_maxiter=cg_maxiter,
-                             cg_impl=cg_impl, shifts=shifts)
+                             shifts=shifts)
     return ROMModel(net, basis, ts=ts, dtype=dtype)
 
 
@@ -543,11 +516,10 @@ class ROMFamilyModel:
                  n_moments=DEFAULT_MOMENTS, ts: float = 0.01,
                  cap_multipliers: Optional[dict] = None,
                  dtype=jnp.float32, basis: Optional[np.ndarray] = None,
-                 solver: str = "auto", cg_tol: float = 1e-10,
-                 cg_maxiter: int = 5000, cg_impl: str = "auto",
-                 shifts: tuple = (0.0,), **rc_opts):
+                 solver: str = "auto", shifts: tuple = (0.0,),
+                 **rc_opts):
         self.rcf = RCFamilyModel(family, cap_multipliers=cap_multipliers,
-                                 dtype=dtype, cg_impl=cg_impl, **rc_opts)
+                                 dtype=dtype, **rc_opts)
         self.family = family
         self.ts = ts
         self.dtype = dtype
@@ -557,12 +529,8 @@ class ROMFamilyModel:
         if basis is None:
             net0 = family.template_network(
                 _resolve_cap_multipliers(family.template, cap_multipliers))
-            # cg_tol/cg_maxiter govern the one-time basis solves, exactly
-            # as on the single-package build(pkg, "rom", ...) path
             basis = krylov_basis(net0, r=r, n_moments=n_moments,
-                                 solver=solver, cg_tol=cg_tol,
-                                 cg_maxiter=cg_maxiter, cg_impl=cg_impl,
-                                 shifts=shifts)
+                                 solver=solver, shifts=shifts)
         self.V = np.asarray(basis, np.float64)
         self._vd = jnp.asarray(self.V, dtype)
 

@@ -23,7 +23,7 @@ choice per query from two ingredients:
       certificate: with ``W = (-G)^-T H^T`` (one block solve per
       router), the observation error of any expanded full-state answer
       x is identically ``W' (P q - (-G) x)`` — the O(E) residual matvec
-      reuses ``kernels/coo_matvec`` on the expanded ROM state, and the
+      is a host f64 sparse product on the expanded ROM state, and the
       certificate is the exact error times a small roundoff safety.
     - *Transient* ROM answers carry a discrete decay bound: the
       full-order ZOH error recursion ``d_(k+1) = Ad d_k + r_k`` with
@@ -73,11 +73,9 @@ import time
 from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.coo_matvec.ops import coo_matvec, coo_plan
 from ..testing import faults
 from .dss import EighZOH, zoh_discretize
 from .fidelity import (register_family_fidelity, register_fidelity,
@@ -232,7 +230,7 @@ class ErrorCertifier:
     solver tier) and — lazily, on the first transient certification —
     the reference rung's eigendecomposition (shared with the router's
     ``dss`` answers via :meth:`reference`). Per-query costs are O(E)
-    residual matvecs on ``kernels/coo_matvec`` (steady) or O(N (r+S))
+    host sparse residual matvecs (steady) or O(N (r+S))
     per step residual products (transient).
     """
 
@@ -249,8 +247,7 @@ class ErrorCertifier:
     FLOOR_REFERENCE = 1e-8
 
     def __init__(self, net: RCNetwork, tags: Optional[list] = None,
-                 solver: str = "auto", cg_tol: float = 1e-10,
-                 cg_maxiter: int = 5000):
+                 solver: str = "auto"):
         self.net = net
         self.h = observation_matrix(net, tags)           # (n_obs, N)
         self._c_sqrt = np.sqrt(np.asarray(net.C, np.float64))
@@ -259,32 +256,19 @@ class ErrorCertifier:
         # the transient bound
         self._h_cnorm = float(np.linalg.norm(
             self.h * self._c_isqrt[None, :], axis=1).max())
-        self._solve = _make_neg_g_solver(
-            net, resolve_solver(solver, net.n), cg_tol=cg_tol,
-            cg_maxiter=cg_maxiter)
+        self._solve = _make_neg_g_solver(net, resolve_solver(solver, net.n))
         self.W = self._solve(self.h.T)                   # (N, n_obs)
-        # O(E) residual matvec on the expanded state: the coo_matvec
-        # kernel under x64 (created AND called inside the context so the
-        # f64 closures stay f64)
-        with jax.experimental.enable_x64():
-            plan = coo_plan(net.rows, net.cols, net.n)
-            gvals = jnp.asarray(net.gvals, jnp.float64)
-            diag = jnp.asarray(net.neg_g_diag(), jnp.float64)
-
-            @jax.jit
-            def neg_g_mv(x):  # (..., N) -> (-G) x
-                return diag * x - coo_matvec(plan, gvals, x)
-
-        self._neg_g_mv_jit = neg_g_mv
+        # O(E) residual matvec on the expanded state: host f64 sparse
+        self._neg_g = net.neg_g_sparse()
         self._ref: Optional[EighZOH] = None
         self._adv_cache: dict = {}
 
     # ------------------------------------------------------------------
     def neg_g_mv(self, x: np.ndarray) -> np.ndarray:
-        """(-G) x via the COO kernel, host f64 in/out, (..., N)."""
-        with jax.experimental.enable_x64():
-            return np.asarray(self._neg_g_mv_jit(
-                jnp.asarray(x, jnp.float64)), np.float64)
+        """(-G) x on the host in f64, x (..., N)."""
+        x = np.asarray(x, np.float64)
+        flat = x.reshape(-1, x.shape[-1])
+        return (self._neg_g @ flat.T).T.reshape(x.shape)
 
     def reference(self) -> EighZOH:
         """The shared full-order f64 reference rung (lazy: steady-only

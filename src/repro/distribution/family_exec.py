@@ -68,17 +68,13 @@ _KEEP_JITS = 8
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    """Version-compat shard_map: ``check_rep=False`` because the family
-    solvers carry ``lax.while_loop``s (batched CG), which the replication
-    checker has no rule for — replication is trivially correct here since
-    the executor never closes over sharded values."""
-    try:
-        from jax.experimental.shard_map import shard_map
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except ImportError:  # newer jax: promoted to jax.shard_map
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
+    """``jax.shard_map`` with ``check_vma=False``: the family solvers
+    carry ``lax.while_loop``s around a ``pallas_call`` (batched CG),
+    which the varying-manual-axes checker has no rule for — replication
+    is trivially correct here since the executor never closes over
+    sharded values."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 class FamilyExecutor:

@@ -5,8 +5,10 @@ of the off-diagonal COO matvec ``y[r] += gvals[e] * x[cols[e]]``. XLA's
 scatter-add lowers poorly on TPU, so the scatter is reformulated as a
 sequence of small one-hot GEMMs over ROW-SORTED edges:
 
-  * the edge list is tiled into blocks of ``be`` edges (grid dim 0,
-    "arbitrary" = sequential, so accumulation into the output is safe);
+  * the batch is tiled into row blocks sized to VMEM (grid dim 0,
+    "parallel") and the edge list into blocks of ``be`` edges (grid
+    dim 1, "arbitrary" = sequential, so accumulation into the output
+    is safe);
   * because the rows are sorted, one tile only touches a narrow window of
     output rows. ``span`` is the host-computed maximum window width over
     all tiles (lane-aligned), so the window is a STATIC shape;
@@ -27,20 +29,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams  # fail at import, naming the attribute
-
 LANE = 128           # TPU lane width; windows and pads align to this
 SUBLANE = 8          # f32 sublane width; the batch dim pads to this
+# The MXU's default precision rounds f32 operands to bf16 (8 mantissa
+# bits). A one-hot GEMM is a gather or scatter, so the values must pass
+# through it unrounded: full-f32 passes.
+EXACT = jax.lax.Precision.HIGHEST
+# VMEM bytes of one (block_b, n_pad) output block. Pallas double-buffers
+# it, so two must fit a v5e core's 16 MiB scoped-VMEM limit beside the
+# edge tiles.
+_OUT_BLOCK_BYTES = 4 << 20
+
+
+def _batch_block(b_pad: int, n_pad: int, itemsize: int) -> int:
+    """Largest multiple of SUBLANE that divides ``b_pad`` and keeps one
+    output block within ``_OUT_BLOCK_BYTES``."""
+    cap = max(SUBLANE, _OUT_BLOCK_BYTES // (n_pad * itemsize))
+    return max(r for r in range(SUBLANE, min(b_pad, cap) + 1, SUBLANE)
+               if b_pad % r == 0)
 
 
 def _segsum_kernel(rows_ref, vals_ref, o_ref, *, span: int):
     """One edge tile: one-hot GEMM into the [base, base+span) row window.
 
-    rows_ref (be, 1) int32 sorted; vals_ref (B, be); o_ref (B, n_pad).
+    rows_ref (be, 1) int32 sorted; vals_ref (bb, be); o_ref (bb, n_pad).
     """
-    i = pl.program_id(0)
+    i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
@@ -56,7 +70,7 @@ def _segsum_kernel(rows_ref, vals_ref, o_ref, *, span: int):
     acc_t = vals_ref.dtype if vals_ref.dtype == jnp.float64 \
         else jnp.float32
     local = jnp.dot(vals_ref[...], sel.astype(vals_ref.dtype),
-                    preferred_element_type=acc_t)
+                    precision=EXACT, preferred_element_type=acc_t)
     o_ref[:, pl.ds(base, span)] += local.astype(o_ref.dtype)
 
 
@@ -75,19 +89,22 @@ def coo_segment_sum_sorted(vals: jnp.ndarray, rows2d: jnp.ndarray,
     assert e_pad % be == 0 and rows2d.shape == (e_pad, 1), \
         (vals.shape, rows2d.shape, be)
     assert n_pad % LANE == 0 and span % LANE == 0, (n_pad, span)
-    grid = (e_pad // be,)
+    assert b_pad % SUBLANE == 0, b_pad
+    bb = _batch_block(b_pad, n_pad, vals.dtype.itemsize)
+    grid = (b_pad // bb, e_pad // be)
     return pl.pallas_call(
         functools.partial(_segsum_kernel, span=span),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((be, 1), lambda i: (i, 0)),
-            pl.BlockSpec((b_pad, be), lambda i: (0, i)),
+            pl.BlockSpec((be, 1), lambda b, i: (i, 0)),
+            pl.BlockSpec((bb, be), lambda b, i: (b, i)),
         ],
-        # every tile revisits the same full output block and accumulates
-        out_specs=pl.BlockSpec((b_pad, n_pad), lambda i: (0, 0)),
+        # every tile of a batch block revisits the same output block
+        # and accumulates
+        out_specs=pl.BlockSpec((bb, n_pad), lambda b, i: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((b_pad, n_pad), vals.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="coo_segment_sum",
     )(rows2d, vals)

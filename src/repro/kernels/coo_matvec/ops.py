@@ -30,7 +30,8 @@ Backend selection (same contract as the other kernel packages):
   'pallas'    — real TPU lowering (target hardware)
   'interpret' — Pallas interpret mode (CPU correctness validation)
   'xla'       — ``jax.ops.segment_sum`` on the sorted edges (CPU default)
-  'auto'      — pallas on TPU, xla elsewhere
+  'auto'      — ``kernels/backend.resolve_backend`` (pallas on a TPU for
+                f32, xla otherwise)
 """
 from __future__ import annotations
 
@@ -40,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..backend import resolve_backend
 from .kernel import LANE, SUBLANE, coo_segment_sum_sorted
 
 
@@ -102,15 +104,10 @@ def coo_plan(rows: np.ndarray, cols: np.ndarray, num_segments: int,
                    rows2d_pad=jnp.asarray(rows_pad[:, None]))
 
 
-def _default_backend() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
-
-
 def _segment_sum_of_sorted(plan: COOPlan, vals_s: jnp.ndarray,
                            backend: str) -> jnp.ndarray:
     """vals_s (..., E) in SORTED edge order -> (..., N) row sums."""
-    if backend == "auto":
-        backend = _default_backend()
+    backend = resolve_backend(backend, vals_s.dtype)
     lead = vals_s.shape[:-1]
     if plan.n_edges == 0:
         return jnp.zeros(lead + (plan.n,), vals_s.dtype)

@@ -20,11 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams  # fail at import, naming the attribute
-
-
 def _matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int):
     k = pl.program_id(2)
 
@@ -32,7 +27,9 @@ def _matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # full-f32 MXU passes: the default rounds f32 operands to bf16
     acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
@@ -64,7 +61,7 @@ def blocked_matmul(x: jnp.ndarray, w: jnp.ndarray, *, bm: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="dss_fused_gemm",

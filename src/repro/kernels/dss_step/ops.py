@@ -4,7 +4,8 @@ Backend selection:
   'pallas'    — real TPU lowering (target hardware)
   'interpret' — Pallas interpret mode (CPU correctness validation)
   'xla'       — pure-jnp reference path (used by CPU benchmarks & dry-run)
-  'auto'      — pallas on TPU, xla elsewhere
+  'auto'      — ``kernels/backend.resolve_backend`` (pallas on a TPU for
+                f32, xla otherwise)
 """
 from __future__ import annotations
 
@@ -13,12 +14,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..backend import resolve_backend
 from .kernel import blocked_matmul
 from .ref import dss_step_ref
-
-
-def _default_backend() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
@@ -38,8 +36,7 @@ def dss_step(theta: jnp.ndarray, q: jnp.ndarray, ad_t: jnp.ndarray,
 
     theta (B, N), q (B, S), ad_t (N, N), bd_t (S, N) -> (B, N).
     """
-    if backend == "auto":
-        backend = _default_backend()
+    backend = resolve_backend(backend, theta.dtype)
     if backend == "xla":
         return dss_step_ref(theta, q, ad_t, bd_t)
     b, n = theta.shape

@@ -18,16 +18,13 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..backend import resolve_backend
 from .kernel import flash_attention_pallas
 from .ref import chunked_gqa, gqa_ref, mha_ref  # noqa: F401
 
 # XLA-path threshold: above this Lq the chunked (flash-style) formulation
 # is used so the (Lq, Lk) score matrix is never materialized.
 _CHUNKED_MIN_LEN = 2048
-
-
-def _default_backend() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 def _kernel_path(q, k, v, causal, scale, interpret):
@@ -75,8 +72,7 @@ _attn_kernel.defvjp(_attn_kernel_fwd, _attn_kernel_bwd)
 def attention(q, k, v, causal: bool = True, scale=None,
               backend: str = "auto") -> jnp.ndarray:
     """GQA attention. q (B,Hq,L,D), k/v (B,Hkv,Lk,D), Hq % Hkv == 0."""
-    if backend == "auto":
-        backend = _default_backend()
+    backend = resolve_backend(backend, q.dtype)
     if scale is None:
         scale = q.shape[-1] ** -0.5
     scale = float(scale)

@@ -16,8 +16,11 @@ single launch, flash-attention style (see ``kernels/flash_attn``):
     (``ops.fused_cg_plan``) reorders the nodes with reverse Cuthill-McKee
     so every edge tile touches a NARROW, host-bounded column window
     [col_base, col_base + col_span) of ``p`` — the window is a static
-    shape, its start rides a per-tile scalar input, and the in-tile
-    column indices are stored relative to it;
+    shape, its per-tile start is a scalar-prefetched SMEM array (a
+    (1, 1) VMEM block per tile is refused by the TPU lowering), and the
+    in-tile column indices are stored relative to it;
+  * both one-hot GEMMs run at full f32 precision (``EXACT``): the MXU's
+    default would round ``p`` and the products to bf16;
   * the LAST edge tile runs the epilogue: add the diagonal term, form the
     ``p·Ap`` / ``r·z`` reductions, the masked alpha/beta, the x/r/p
     updates and the new residual norm — all on the full state resident in
@@ -47,11 +50,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..coo_matvec.kernel import LANE, SUBLANE  # shared alignment contract
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams  # fail at import, naming the attribute
+from ..coo_matvec.kernel import (  # shared alignment/precision contract
+    EXACT, LANE, SUBLANE)
 
 __all__ = ["LANE", "SUBLANE", "fused_cg_step_pallas"]
 
@@ -63,8 +63,9 @@ def _cg_step_kernel(colbase_ref, rows_ref, cols_ref, gv_ref, diag_ref,
     """One grid step: accumulate one edge tile of ``offdiag @ p``; on the
     final tile, run the whole CG-iteration epilogue.
 
-    colbase_ref (1, 1) int32; rows_ref (be, 1) int32 sorted ABSOLUTE;
-    cols_ref (be, 1) int32 RELATIVE to colbase; gv_ref (bb, be);
+    colbase_ref (n_tiles,) int32 in SMEM (scalar prefetch); rows_ref
+    (be, 1) int32 sorted ABSOLUTE; cols_ref (be, 1) int32 RELATIVE to
+    colbase; gv_ref (bb, be);
     diag/x/r/p (bb, n_pad); rz/rn2/tol2 (bb, 1); it (bb, 1) int32;
     ap_ref (bb, n_pad) VMEM scratch.
     """
@@ -79,12 +80,13 @@ def _cg_step_kernel(colbase_ref, rows_ref, cols_ref, gv_ref, diag_ref,
     acc_t = dtype if dtype == jnp.float64 else jnp.float32
 
     # ---- gather p over the tile's column window (one-hot GEMM) ----------
-    cbase = pl.multiple_of(colbase_ref[0, 0], LANE)
+    cbase = pl.multiple_of(colbase_ref[i], LANE)
     pwin = p_ref[:, pl.ds(cbase, col_span)]              # (bb, col_span)
     selg = (cols_ref[...] == jax.lax.broadcasted_iota(
         jnp.int32, (be, col_span), 1)).astype(dtype)      # (be, col_span)
     # pg[b, e] = pwin[b, cols_rel[e]]
     pg = jax.lax.dot_general(pwin, selg, (((1,), (1,)), ((), ())),
+                             precision=EXACT,
                              preferred_element_type=acc_t).astype(dtype)
     contrib = gv_ref[...] * pg                           # (bb, be)
 
@@ -92,7 +94,8 @@ def _cg_step_kernel(colbase_ref, rows_ref, cols_ref, gv_ref, diag_ref,
     rbase = pl.multiple_of((rows_ref[0, 0] // LANE) * LANE, LANE)
     selr = (rows_ref[...] == (jax.lax.broadcasted_iota(
         jnp.int32, (be, row_span), 1) + rbase)).astype(dtype)
-    local = jnp.dot(contrib, selr, preferred_element_type=acc_t)
+    local = jnp.dot(contrib, selr, precision=EXACT,
+                    preferred_element_type=acc_t)
     ap_ref[:, pl.ds(rbase, row_span)] += local.astype(ap_ref.dtype)
 
     # ---- final tile: the rest of the CG iteration -----------------------
@@ -128,7 +131,7 @@ def fused_cg_step_pallas(colbase, rows2d, cols2d, gvals, diag, x, r, p,
                          interpret: bool = False):
     """One fused Jacobi-PCG iteration on pre-padded operands.
 
-    colbase (n_tiles, 1) int32 lane-aligned window starts; rows2d /
+    colbase (n_tiles,) int32 lane-aligned window starts; rows2d /
     cols2d (e_pad, 1) int32 (rows absolute sorted, cols relative);
     gvals (b_pad, e_pad) zero-padded; diag/x/r/p (b_pad, n_pad) with
     ``diag`` one-padded; rz/rn2/tol2 (b_pad, 1); it (b_pad, 1) int32.
@@ -146,17 +149,16 @@ def fused_cg_step_pallas(colbase, rows2d, cols2d, gvals, diag, x, r, p,
     dtype = x.dtype
     acc_t = dtype if dtype == jnp.float64 else jnp.float32
 
-    state_spec = pl.BlockSpec((block_b, n_pad), lambda b, i: (b, 0))
-    scalar_spec = pl.BlockSpec((block_b, 1), lambda b, i: (b, 0))
-    return pl.pallas_call(
-        functools.partial(_cg_step_kernel, n_tiles=n_tiles,
-                          row_span=row_span, col_span=col_span),
+    # index maps take the scalar-prefetched colbase ref as a trailing arg
+    state_spec = pl.BlockSpec((block_b, n_pad), lambda b, i, cb: (b, 0))
+    scalar_spec = pl.BlockSpec((block_b, 1), lambda b, i, cb: (b, 0))
+    edge_spec = pl.BlockSpec((be, 1), lambda b, i, cb: (i, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                                # colbase
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, i: (i, 0)),       # colbase
-            pl.BlockSpec((be, 1), lambda b, i: (i, 0)),      # rows
-            pl.BlockSpec((be, 1), lambda b, i: (i, 0)),      # cols (rel)
-            pl.BlockSpec((block_b, be), lambda b, i: (b, i)),  # gvals
+            edge_spec, edge_spec,                             # rows, cols
+            pl.BlockSpec((block_b, be), lambda b, i, cb: (b, i)),  # gvals
             state_spec,                                       # diag
             state_spec, state_spec, state_spec,               # x, r, p
             scalar_spec, scalar_spec,                         # rz, rn2
@@ -164,6 +166,12 @@ def fused_cg_step_pallas(colbase, rows2d, cols2d, gvals, diag, x, r, p,
         ],
         out_specs=[state_spec, state_spec, state_spec,
                    scalar_spec, scalar_spec, scalar_spec],
+        scratch_shapes=[pltpu.VMEM((block_b, n_pad), acc_t)],
+    )
+    return pl.pallas_call(
+        functools.partial(_cg_step_kernel, n_tiles=n_tiles,
+                          row_span=row_span, col_span=col_span),
+        grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b_pad, n_pad), dtype),      # x'
             jax.ShapeDtypeStruct((b_pad, n_pad), dtype),      # r'
@@ -172,8 +180,7 @@ def fused_cg_step_pallas(colbase, rows2d, cols2d, gvals, diag, x, r, p,
             jax.ShapeDtypeStruct((b_pad, 1), dtype),          # rn2'
             jax.ShapeDtypeStruct((b_pad, 1), jnp.int32),      # it'
         ],
-        scratch_shapes=[pltpu.VMEM((block_b, n_pad), acc_t)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="fused_cg_step",

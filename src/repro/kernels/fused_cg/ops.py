@@ -47,7 +47,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..coo_matvec.kernel import coo_segment_sum_sorted
-from ..coo_matvec.ops import _default_backend, _round_up
+from ..backend import resolve_backend
+from ..coo_matvec.ops import _round_up
 from .kernel import LANE, SUBLANE, fused_cg_step_pallas
 
 __all__ = [
@@ -128,7 +129,7 @@ class FusedCGPlan:
     cols_sorted: jnp.ndarray  # (E,) int32, absolute, permuted space
     rows2d: jnp.ndarray      # (e_pad, 1) int32
     cols2d: jnp.ndarray      # (e_pad, 1) int32, tile-relative
-    col_base: jnp.ndarray    # (n_tiles, 1) int32, lane-aligned
+    col_base: jnp.ndarray    # (n_tiles,) int32, lane-aligned
     ell_cols: jnp.ndarray    # (n, ell_k) int32
     ell_src: jnp.ndarray     # (n, ell_k) int32 into ORIGINAL edge order
     ell_mask: jnp.ndarray    # (n, ell_k) bool
@@ -216,7 +217,7 @@ def _freeze_plan(n, e, block_edges, row_span, col_span, n_pad, e_pad,
         edge_perm=as_i32(order),
         rows_sorted=as_i32(rows_s), cols_sorted=as_i32(cols_s),
         rows2d=as_i32(rows_p[:, None]), cols2d=as_i32(cols_rel[:, None]),
-        col_base=as_i32(col_base[:, None]),
+        col_base=as_i32(col_base),
         ell_cols=as_i32(ell_cols), ell_src=as_i32(ell_src),
         ell_mask=jnp.asarray(ell_mask),
     )
@@ -371,18 +372,18 @@ def fused_cg_solve(plan: FusedCGPlan, diag, gvals, rhs, x0=None, *,
     (the off-diagonal magnitude being subtracted); rhs (..., N); leading
     axes broadcast. Returns ``(x, CGStats)`` with x matching the
     broadcast leading shape. ``impl``: "auto" | "fused" | "unfused";
-    ``backend``: "auto" | "pallas" | "interpret" | "xla".
+    ``backend``: "auto" | "pallas" | "interpret" | "xla" (see
+    ``kernels/backend.py``).
     """
     impl = resolve_cg_impl(impl)
-    if backend == "auto":
-        backend = _default_backend()
-    if plan.n_edges == 0 and backend in ("pallas", "interpret"):
-        backend = "xla"  # no tiles worth launching
     n, e = plan.n, plan.n_edges
     diag = jnp.asarray(diag)
     gvals = jnp.asarray(gvals)
     rhs = jnp.asarray(rhs)
     dtype = rhs.dtype
+    backend = resolve_backend(backend, dtype)
+    if e == 0 and backend in ("pallas", "interpret"):
+        backend = "xla"  # no tiles worth launching
     lead = jnp.broadcast_shapes(
         diag.shape[:-1], gvals.shape[:-1], rhs.shape[:-1],
         () if x0 is None else jnp.shape(x0)[:-1])

@@ -19,11 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams  # fail at import, naming the attribute
-
-
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, s_final_ref,
                 state_ref, *, nc: int):
     c_idx = pl.program_id(2)
@@ -105,7 +100,7 @@ def ssd_scan_pallas(x, dt, a, bm, cm, chunk: int = 64,
             jax.ShapeDtypeStruct((b, h, n, p), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="ssd_chunked_scan",

@@ -6,12 +6,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..backend import resolve_backend
 from .kernel import ssd_scan_pallas
 from .ref import ssd_chunked_jnp, ssd_decode_step, ssd_ref  # noqa: F401
-
-
-def _default_backend() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "backend"))
@@ -22,8 +19,7 @@ def ssd_scan(x, dt, a, bm, cm, chunk: int = 64, backend: str = "auto"):
     y (B,L,H,P), state (B,H,P,N). Pads L to a chunk multiple internally
     (zero dt/x padding is exact: decay 1, contribution 0).
     """
-    if backend == "auto":
-        backend = _default_backend()
+    backend = resolve_backend(backend, x.dtype)
     b, l, h, p = x.shape
     pad = (-l) % chunk
     if pad:

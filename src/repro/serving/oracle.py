@@ -68,7 +68,7 @@ models, not one-shot ``build()`` scripts (cf. 3D-ICE 4.0's server mode).
     amortizing the ~98 s 8k-node basis build.
 
 ``x64=True`` builds and executes every model under
-``jax.experimental.enable_x64()`` *on the worker thread* (the flag is
+``repro.runtime.x64()`` *on the worker thread* (the flag is
 thread-local — a client-side context manager would not reach the
 worker); the f64 parity tests run the service in this mode.
 """
@@ -80,13 +80,12 @@ import time
 import traceback
 from typing import Dict, Optional, Tuple
 
-import jax
-import jax.experimental
 import numpy as np
 
 from ..core.dtpm import ThermalManager
 from ..core.fidelity import build, build_family
 from ..core.geometry import Package
+from ..runtime import x64
 from ..testing import faults
 from .batcher import ContinuousBatcher
 from .cache import ModelCache
@@ -271,7 +270,7 @@ class ThermalOracle:
 
     #: build opts that shape the ROM Krylov basis — everything else
     #: (ts, dtype, ...) reuses the same persisted basis.
-    _BASIS_OPTS = ("r", "n_moments", "solver", "cg_tol", "cg_maxiter")
+    _BASIS_OPTS = ("r", "n_moments", "solver")
 
     def _build(self, target, fidelity: str, opts: dict):
         """Build a model; with a disk tier attached, ROM builds
@@ -294,7 +293,7 @@ class ThermalOracle:
             else:
                 persist_key = basis_key
         if self.x64:
-            with jax.experimental.enable_x64():
+            with x64():
                 model = fn(target, fidelity, **opts)
         else:
             model = fn(target, fidelity, **opts)
@@ -512,7 +511,7 @@ class ThermalOracle:
     def _execute(self, group_key: tuple, group) -> None:
         try:
             if self.x64:
-                with jax.experimental.enable_x64():
+                with x64():
                     self._answer(group)
             else:
                 self._answer(group)

@@ -41,7 +41,7 @@ def _rel(a, b, floor=1e-3):
 @pytest.mark.parametrize("system", SYSTEMS)
 def test_adjoint_grad_matches_dense_and_fd(system):
     pkg, _ = package_from_name(system)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fam, cg, dense = _grad_models(pkg)
         p0 = fam.sample_params(1, seed=5)[0]
         s = len(fam.sym.source_names)
@@ -77,7 +77,7 @@ def test_adjoint_grad_matches_dense_and_fd(system):
 # backward-pass cost contract: ONE adjoint solve per candidate
 # ---------------------------------------------------------------------------
 def test_backward_is_one_adjoint_solve():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fam, cg, _ = _grad_models(make_2p5d_package(16))
         params = fam.sample_params(3, seed=6)
         q = np.full(16, 2.0)
@@ -102,7 +102,7 @@ def test_run_value_and_grad_pad_masking():
     """B=5 over chunk_size=2 pads to 6: the pad row (base_params) must
     be evaluated but masked — values/grads of the 5 real rows identical
     to the unchunked, unpadded batch."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fam = PackageFamily(make_2p5d_package(16),
                             params=("grid_offsets",))
         plain = RCFamilyModel(fam, dtype=jnp.float64, solver="cg")
@@ -123,7 +123,7 @@ def test_run_value_and_grad_pad_masking():
 # ROM transient objective: reverse-differentiable rollout vs FD
 # ---------------------------------------------------------------------------
 def test_rom_transient_grad_matches_fd():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fam = PackageFamily(make_2p5d_package(16),
                             params=("grid_offsets",))
         rom = build_family(fam, "rom", dtype=jnp.float64)
@@ -148,7 +148,7 @@ def test_rom_transient_grad_matches_fd():
 # optimizer: improves on its starts, stays in-family, respects budget
 # ---------------------------------------------------------------------------
 def test_optimize_family_improves_and_stays_valid():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fam = PackageFamily(make_2p5d_package(16),
                             params=("grid_offsets",))
         model = RCFamilyModel(fam, dtype=jnp.float64, solver="cg")
@@ -172,7 +172,7 @@ def test_optimize_family_lbfgs_avoids_degenerate_corner():
     and reported the ambient temperature as a bogus 'optimum'. The
     frac-shrunk projection box plus the non-finite guard must keep every
     reported start value physical (above ambient + the mean rise)."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fam = PackageFamily(make_2p5d_package(16),
                             params=("grid_offsets",))
         model = RCFamilyModel(fam, dtype=jnp.float64, solver="cg")
@@ -196,7 +196,7 @@ except ImportError:
 
 
 def _grad_parity_one(pkg):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fam, cg, dense = _grad_models(pkg, params=("grid_offsets",))
         if fam.n_params == 0:  # single-chiplet: no offsets to move
             return

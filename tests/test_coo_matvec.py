@@ -77,7 +77,7 @@ def test_broadcast_shared_gvals(backend):
 def test_f64_parity(backend):
     n, e, b = 220, 1700, 4
     rows, cols = _random_pattern(n, e)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         plan = coo_plan(rows, cols, n)
         gvals = jnp.asarray(RNG.normal(size=(b, e)), jnp.float64)
         x = jnp.asarray(RNG.normal(size=(b, n)), jnp.float64)

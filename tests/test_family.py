@@ -89,7 +89,7 @@ def test_steady_matches_loop_2p5d(fam16):
     params = np.vstack([fam16.base_params(),
                         fam16.sample_params(3, seed=1)])
     q = np.full((4, 16), 3.0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         sim = build_family(fam16, "rc", dtype=jnp.float64)
         th = sim.steady_state_batch(params, q)
         temps = np.asarray(sim.observe_batch(th, params))
@@ -102,7 +102,7 @@ def test_steady_matches_loop_3d():
                         params=("grid_offsets",))
     params = np.vstack([fam.base_params(), fam.sample_params(2, seed=2)])
     q = np.full((3, 48), 1.2)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         sim = build_family(fam, "rc", dtype=jnp.float64)
         th = sim.steady_state_batch(params, q)
         temps = np.asarray(sim.observe_batch(th, params))
@@ -113,7 +113,7 @@ def test_steady_matches_loop_3d():
 def test_steady_degenerate_b1(fam16):
     params = fam16.sample_params(1, seed=3)
     q = np.full((1, 16), 2.5)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         sim = build_family(fam16, "rc", dtype=jnp.float64)
         temps = np.asarray(sim.observe_batch(
             sim.steady_state_batch(params, q), params))
@@ -126,7 +126,7 @@ def test_transient_matches_loop(fam16):
     params = fam16.sample_params(2, seed=4)
     T, dt = 25, 0.01
     q = np.full((T, 2, 16), 2.0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         sim = build_family(fam16, "rc", dtype=jnp.float64)
         obs = np.asarray(sim.simulate_family(params, q, dt))
         assert obs.shape == (T, 2, 16)
@@ -142,7 +142,7 @@ def test_dss_family_matches_loop(fam16):
     params = fam16.sample_params(2, seed=5)
     T = 25
     q = np.full((T, 2, 16), 2.0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         sim = build_family(fam16, "dss", ts=0.01, dtype=jnp.float64)
         obs = np.asarray(sim.simulate_family(params, q))
         for b in range(2):
@@ -178,7 +178,7 @@ def test_cg_family_transient_casts_params(fam16):
     """Regression: the cg-tier family transient must cast params to the
     model dtype inside the trace — an f32 model fed float64 params under
     enable_x64 raised a lax.scan carry-dtype mismatch."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         sim = build_family(fam16, "rc", solver="cg")  # dtype f32
         params = fam16.sample_params(2, seed=12)      # float64 host draw
         q = np.full((5, 2, 16), 2.0)
@@ -190,7 +190,7 @@ def test_transient_cross_solver_family(fam16):
     params = fam16.sample_params(3, seed=7)
     T, dt = 25, 0.01
     q = np.full((T, 3, 16), 2.0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         dense = build_family(fam16, "rc", dtype=jnp.float64,
                              solver="dense")
         cg = build_family(fam16, "rc", dtype=jnp.float64, solver="cg")
@@ -204,7 +204,7 @@ def test_steady_degenerate_b1_cg(fam16):
     — the degenerate case of the solver tier's batched path."""
     params = fam16.sample_params(1, seed=8)
     q = np.full((1, 16), 2.5)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         sim = build_family(fam16, "rc", dtype=jnp.float64, solver="cg")
         temps = np.asarray(sim.observe_batch(
             sim.steady_state_batch(params, q), params))
@@ -225,7 +225,7 @@ def test_grad_peak_steady_through_numeric_phase():
                         params=("grid_offsets", "htc_top",
                                 "thickness:tim"))
     q = np.full((16,), 3.0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         sim = build_family(fam, "rc", dtype=jnp.float64)
         p0 = jnp.asarray(fam.sample_params(1, seed=11)[0])
 

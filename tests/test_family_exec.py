@@ -56,7 +56,7 @@ def test_shared_executor_namespaces_peer_models(fam):
     Regression: a second family over a DIFFERENT package answered with
     the first family's temperatures when keys collided."""
     ex = FamilyExecutor()
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         a = build_family(fam, "rc", dtype=jnp.float64, executor=ex)
         fam4 = PackageFamily(make_2p5d_package(4),
                              params=("grid_offsets",))
@@ -90,7 +90,7 @@ def test_chunked_steady_matches_unchunked(fam):
     chunks) must match the one-call path and the per-package loop."""
     params = np.vstack([fam.base_params(), fam.sample_params(6, seed=1)])
     q = np.full((7, 16), 3.0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         one_call = build_family(fam, "rc", dtype=jnp.float64)
         chunked = build_family(fam, "rc", dtype=jnp.float64, chunk_size=2)
         t_ref = np.asarray(one_call.observe_batch(
@@ -109,7 +109,7 @@ def test_chunked_transient_matches_unchunked(fam):
     params = fam.sample_params(5, seed=2)
     T, dt = 12, 0.01
     q = np.full((T, 5, 16), 2.0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         one_call = build_family(fam, "rc", dtype=jnp.float64)
         chunked = build_family(fam, "rc", dtype=jnp.float64, chunk_size=2)
         o_ref = np.asarray(one_call.simulate_family(params, q, dt))
@@ -144,7 +144,7 @@ def test_mesh_steady_matches_vmap_nondivisible(fam):
     f64, including non-divisible B via padding."""
     params = np.vstack([fam.base_params(), fam.sample_params(10, seed=4)])
     q = np.full((11, 16), 3.0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         ref = build_family(fam, "rc", dtype=jnp.float64)
         t_ref = np.asarray(ref.observe_batch(
             ref.steady_state_batch(params, q), params))
@@ -161,7 +161,7 @@ def test_mesh_transients_match_vmap_rc_dss_rom(fam):
     params = np.vstack([fam.base_params(), fam.sample_params(6, seed=5)])
     T = 10
     q = np.full((T, 7, 16), 2.0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         for fid, opts in (("rc", {}), ("rc", {"solver": "cg"}),
                           ("dss", {"ts": 0.01}), ("rom", {"ts": 0.01})):
             ref = build_family(fam, fid, dtype=jnp.float64, **opts)
@@ -176,7 +176,7 @@ def test_mesh_transients_match_vmap_rc_dss_rom(fam):
 def test_mesh_rom_steady_matches_vmap(fam):
     params = fam.sample_params(9, seed=6)
     q = np.full((9, 16), 3.0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         ref = build_family(fam, "rom", dtype=jnp.float64)
         sim = build_family(fam, "rom", dtype=jnp.float64, mesh=8)
         t_ref = np.asarray(ref.observe_batch(
@@ -193,7 +193,7 @@ def test_mesh_composes_with_chunk_streaming(fam):
     stream lands on the host chunk by chunk."""
     params = fam.sample_params(40, seed=7)
     q = np.full((40, 16), 3.0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         ref = build_family(fam, "rc", dtype=jnp.float64)
         sim = build_family(fam, "rc", dtype=jnp.float64, mesh=8,
                            chunk_size=16)
@@ -224,7 +224,7 @@ q = np.full((5, 16), 3.0)               # B=5: non-divisible by 8
 T = 8
 qt = np.full((T, 5, 16), 2.0)
 errs = {}
-with jax.experimental.enable_x64():
+with jax.enable_x64(True):
     rc_ref = build_family(fam, "rc", dtype=jnp.float64)
     rc_8 = build_family(fam, "rc", dtype=jnp.float64, mesh=8)
     t_ref = np.asarray(rc_ref.observe_batch(

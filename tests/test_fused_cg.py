@@ -49,7 +49,7 @@ def test_parity_vs_dense_oracle_f64(n, e, impl, backend):
     rows, cols, gvals, diag = random_spd_system(n, e, seed=n + e)
     rhs = np.random.default_rng(1).normal(size=n)
     ref = dense_solve_ref(diag, gvals, rows, cols, rhs)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         plan = fused_cg_plan(rows, cols, n)
         x, stats = fused_cg_solve(plan, jnp.asarray(diag),
                                   jnp.asarray(gvals), jnp.asarray(rhs),
@@ -66,7 +66,7 @@ def test_batched_rhs_parity(b, impl, backend):
     rows, cols, gvals, diag = random_spd_system(n, e, seed=7)
     rhs = np.random.default_rng(2).normal(size=(b, n))
     ref = dense_solve_ref(diag, gvals, rows, cols, rhs)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         plan = fused_cg_plan(rows, cols, n)
         x, stats = fused_cg_solve(plan, jnp.asarray(diag),
                                   jnp.asarray(gvals), jnp.asarray(rhs),
@@ -108,7 +108,7 @@ def test_real_table6_pattern_matches_dense_f64():
     q = np.full(len(net.grid.source_names), 2.0)
     rhs = net.P @ q
     ref = dense_solve_ref(diag, net.gvals, net.rows, net.cols, rhs)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         plan = fused_cg_plan(net.rows, net.cols, net.n)
         for impl, backend in PAIRINGS:
             x, stats = fused_cg_solve(
@@ -124,7 +124,7 @@ def test_empty_pattern_degenerates_to_diagonal_solve():
     n = 40
     diag = np.linspace(1.0, 3.0, n)
     rhs = np.random.default_rng(5).normal(size=n)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         plan = fused_cg_plan(np.zeros(0, np.int32), np.zeros(0, np.int32),
                              n)
         for impl, backend in PAIRINGS:
@@ -144,7 +144,7 @@ def test_warm_start_and_zero_rhs_rows():
     rows, cols, gvals, diag = random_spd_system(n, e, seed=11)
     rhs = np.random.default_rng(6).normal(size=(3, n))
     rhs[1] = 0.0
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         plan = fused_cg_plan(rows, cols, n)
         x, st = fused_cg_solve(plan, jnp.asarray(diag),
                                jnp.asarray(gvals), jnp.asarray(rhs),
@@ -207,7 +207,7 @@ def test_pcg_loop_matches_fused_jacobi():
     n, e = 129, 511
     rows, cols, gvals, diag = random_spd_system(n, e, seed=17)
     rhs = np.random.default_rng(8).normal(size=(4, n))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         plan = fused_cg_plan(rows, cols, n)
         xf, stf = fused_cg_solve(plan, jnp.asarray(diag),
                                  jnp.asarray(gvals), jnp.asarray(rhs),
@@ -268,7 +268,7 @@ if _HAVE_HYPOTHESIS:
     def test_fused_matches_unfused_on_random_geometries(pkg, p_chip):
         """Fused and unfused CG steady observations agree <=1e-6 degC
         on random valid geometries (f64)."""
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             net = build_network(pkg)
             temps = {}
             for impl in ("fused", "unfused"):

@@ -87,7 +87,7 @@ def test_rom_reduced_g_stays_spd(pkg, n_moments):
 def test_cg_solver_matches_dense_steady(pkg, p_chip):
     """The matrix-free CG tier reproduces the dense steady state to
     <=1e-6 degC on random valid geometries (f64)."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         net = build_network(pkg)
         dense = ThermalRCModel(net, dtype=jnp.float64, solver="dense")
         cg = ThermalRCModel(net, dtype=jnp.float64, solver="cg")

@@ -68,7 +68,7 @@ def test_heatmap_shape(small_pkg):
 def _cross_solver_err(system, p_chip=3.0):
     pkg, s = package_from_name(system)
     q = np.full(s, p_chip)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         dense = build(pkg, "rc", dtype=jnp.float64, solver="dense")
         cg = build(pkg, "rc", dtype=jnp.float64, solver="cg")
         t_dense = np.asarray(dense.observe(dense.steady_state(q)))
@@ -97,7 +97,7 @@ def test_refined_f32_cg_matches_f64_dense(system):
     'f64-free CG' ROADMAP headroom item."""
     pkg, s = package_from_name(system)
     q = np.full(s, 3.0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         dense = build(pkg, "rc", dtype=jnp.float64, solver="dense")
         ref = np.asarray(dense.observe(dense.steady_state(q)))
     cg = build(pkg, "rc", solver="cg")  # default f32, x64 NOT enabled
@@ -113,7 +113,7 @@ def test_refined_cg_threads_through_dss_steady():
     f64 dense fixed point (no x64 anywhere)."""
     pkg = make_2p5d_package(4)
     q = np.full(4, 3.0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         dense = build(pkg, "dss", ts=0.01, dtype=jnp.float64,
                       solver="dense")
         ref = np.asarray(dense.observe(dense.steady_state(q)))
@@ -126,7 +126,7 @@ def test_transient_cross_solver(small_pkg):
     """BE and TRAP integrators: matrix-free twin vs dense factorization."""
     dt = 0.01
     q = np.full((40, 4), 2.0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         dense = build(small_pkg, "rc", dtype=jnp.float64, solver="dense")
         cg = build(small_pkg, "rc", dtype=jnp.float64, solver="cg")
         for method in ("be_chol", "trap"):
@@ -140,7 +140,7 @@ def test_transient_cross_solver(small_pkg):
 def test_dss_steady_cross_solver(small_pkg):
     """DSS ZOH fixed point vs the matrix-free continuous fixed point."""
     q = np.full(4, 3.0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         dense = build(small_pkg, "dss", ts=0.01, dtype=jnp.float64,
                       solver="dense")
         cg = build(small_pkg, "dss", ts=0.01, dtype=jnp.float64,

@@ -175,7 +175,7 @@ def test_rom_family_matches_loop():
     q = np.full((3, 16), 3.0)
     t_steps = 25
     q_traj = np.full((t_steps, 3, 16), 2.0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         sim = build_family(fam, "rom", ts=DT, dtype=jnp.float64)
         temps = np.asarray(sim.observe_batch(
             sim.steady_state_batch(params, q), params))

@@ -82,7 +82,7 @@ def test_f64_parity_all_request_kinds():
                      [r_steady, r_tran, r_dtpm] + r_fs + r_ft]
     assert [r.status for r in responses] == ["ok"] * len(responses)
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         m = build(pkg, "rom", **opts)
         ref_steady = np.asarray(m.observe(m.steady_state(q)))
         ref_tran = np.asarray(m.make_simulator(DT)(m.zero_state(),

@@ -1,0 +1,10 @@
+"""``cg.iters_per_candidate``: mean Jacobi-PCG iterations a candidate of
+the window's sweeps spent live, from the program's per-row
+``CGStats.iterations``."""
+
+
+def read(ctx):
+    counts = ctx["counts"]
+    if not counts.get("candidates") or "cg_iterations" not in counts:
+        return None
+    return counts["cg_iterations"] / counts["candidates"]

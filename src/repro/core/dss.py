@@ -36,6 +36,7 @@ import scipy.linalg
 
 from ..kernels.dss_step.ops import dss_rollout, dss_step
 from ..kernels.fused_cg.ops import all_finite, record_fallback
+from ..runtime import span
 from ..testing import faults
 from .fidelity import (register_family_fidelity,
                        register_fidelity)
@@ -145,14 +146,17 @@ class DSSModel:
         speedup (DESIGN.md §2) — the DSS step needs no vmap wrapper (unlike
         the other fidelities' shared ``simulate_batch_via_vmap`` helper).
         ``dt`` other than the built ``ts`` regenerates from the retained
-        continuous-time arrays (milliseconds).
+        continuous-time arrays (milliseconds). The launch is the span
+        ``dss.dispatch``.
         """
         if dt is not None and abs(dt - self.ts) > 1e-12:
             return self._regenerated(dt).simulate_batch(
                 theta0, q_traj, backend=backend)
-        thetas = dss_rollout(theta0, q_traj, self.ad_t, self.bd_t,
-                             backend=backend)
-        return jnp.einsum("tbn,on->tbo", thetas, self.H) + self.t_ambient
+        with span("dss.dispatch"):
+            thetas = dss_rollout(theta0, q_traj, self.ad_t, self.bd_t,
+                                 backend=backend)
+            return jnp.einsum("tbn,on->tbo", thetas, self.H) \
+                + self.t_ambient
 
     # -- common ThermalSimulator protocol -----------------------------------
     def _regenerated(self, ts: float) -> "DSSModel":

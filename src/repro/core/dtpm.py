@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..runtime import span
 from .dss import DSSModel
 
 
@@ -111,16 +112,19 @@ class ThermalManager:
         while mutating t_max/t_target/..., swapping the model, or a
         model regeneration still take effect. The cache holds STRONG
         references to the keyed objects, so identity comparison cannot
-        be fooled by garbage-collected id reuse.
+        be fooled by garbage-collected id reuse. The launch is the span
+        ``dtpm.dispatch``, with ``first=1`` when this call built the
+        closure (its launch compiles).
         """
         key = (self.t_max, self.t_target, self.down, self.up,
                self.min_throttle, self.dss.t_ambient)
         refs = (self.dss, self.dss.ad, self.dss.bd, self.dss.H)
         cached = getattr(self, "_run_cache", None)
-        if cached is None or cached[0] != key or \
-                any(a is not b for a, b in zip(cached[1], refs)):
+        built = cached is None or cached[0] != key or \
+            any(a is not b for a, b in zip(cached[1], refs))
+        if built:
             @jax.jit
-            def go(traj):
+            def dtpm_scan(traj):
                 def body(st, p):
                     st, info = self.update(st, p)
                     return st, (info["t_max"], info["throttle"])
@@ -129,8 +133,9 @@ class ThermalManager:
                                                traj)
                 return st, tmax, thr
 
-            self._run_cache = (key, refs, go)
-        return self._run_cache[2](powers_traj)
+            self._run_cache = (key, refs, dtpm_scan)
+        with span("dtpm.dispatch", first=int(built)):
+            return self._run_cache[2](powers_traj)
 
     def serve_trace(self, powers_traj):
         """Answer one serving request: ``(t_max_trace, telemetry)``.
@@ -142,19 +147,23 @@ class ThermalManager:
         temperature, violation count, throttle behaviour, remaining
         headroom to ``t_max``, and the pre-emptive checkpoint
         recommendation. Host numpy out (serving responses are consumed
-        on client threads, not inside jit).
+        on client threads, not inside jit). Spans: ``dtpm.serve`` around
+        ``dtpm.dispatch`` (in :meth:`run`) and ``dtpm.land``, the reads
+        of the scan's outputs.
         """
-        state, tmax, thr = self.run(powers_traj)
-        tmax = np.asarray(tmax)
-        thr = np.asarray(thr)
-        telemetry = {
-            "t_max_peak": float(tmax.max()),
-            "t_max_final": float(tmax[-1]),
-            "violations": int(state.violations),
-            "min_throttle": float(thr.min()),
-            "mean_throttle": float(thr.mean()),
-            "headroom_c": float(self.t_max - tmax.max()),
-            "throttle_traj": thr,
-            "checkpoint_recommended": self.should_checkpoint(state),
-        }
+        with span("dtpm.serve"):
+            state, tmax, thr = self.run(powers_traj)
+            with span("dtpm.land"):
+                tmax = np.asarray(tmax)
+                thr = np.asarray(thr)
+                telemetry = {
+                    "t_max_peak": float(tmax.max()),
+                    "t_max_final": float(tmax[-1]),
+                    "violations": int(state.violations),
+                    "min_throttle": float(thr.min()),
+                    "mean_throttle": float(thr.mean()),
+                    "headroom_c": float(self.t_max - tmax.max()),
+                    "throttle_traj": thr,
+                    "checkpoint_recommended": self.should_checkpoint(state),
+                }
         return tmax, telemetry

@@ -33,6 +33,7 @@ from ..kernels.fused_cg.adjoint import make_implicit_steady
 from ..kernels.fused_cg.ops import (CGStats, fused_cg_plan, fused_cg_solve,
                                     pcg_loop, resolve_cg_impl,
                                     warn_unconverged)
+from ..runtime import span
 from .assembly import NumericAssembly, adjacency_within, overlap_between
 from .fidelity import (register_family_fidelity, register_fidelity,
                        resolve_solver, simulate_batch_via_vmap)
@@ -876,7 +877,9 @@ class RCFamilyModel:
         are thermally similar, so the carry saves iterations). Per-solve
         convergence stats land on ``self.last_cg_stats`` (a (B,)-shaped
         :class:`CGStats`), with a host-side warning when any candidate's
-        solve hit the iteration cap."""
+        solve hit the iteration cap. Spans: ``rc.steady`` around the
+        executor's spans and ``rc.check``, that warning's read of the
+        stats."""
         def _steady(x0, params, q):
             def net(p):
                 v = self._network(p)
@@ -889,14 +892,16 @@ class RCFamilyModel:
             th, stats = self._pcg(gvals, gconv, rhs, x0)
             return (th, stats), th
 
-        th, stats = self.exec.run(
-            f"{self._ns}:rc_steady", _steady, (params, q_src),
-            in_axes=(0, 0),
-            out_axis=0, pad_rows=(self._pad_param_row, None),
-            make_carry=lambda b: jnp.zeros((b, self.n), self.dtype))
-        if not isinstance(th, jax.core.Tracer):
-            self.last_cg_stats = stats
-            warn_unconverged(stats, "rc family steady CG")
+        with span("rc.steady"):
+            th, stats = self.exec.run(
+                f"{self._ns}:rc_steady", _steady, (params, q_src),
+                in_axes=(0, 0),
+                out_axis=0, pad_rows=(self._pad_param_row, None),
+                make_carry=lambda b: jnp.zeros((b, self.n), self.dtype))
+            if not isinstance(th, jax.core.Tracer):
+                self.last_cg_stats = stats
+                with span("rc.check"):
+                    warn_unconverged(stats, "rc family steady CG")
         return th
 
     def observe_batch(self, theta, params) -> jnp.ndarray:
@@ -906,10 +911,11 @@ class RCFamilyModel:
             v = self._network(p.astype(self.dtype))
             return v["H"] @ th.astype(self.dtype) + v["t_ambient"]
 
-        return self.exec.run(f"{self._ns}:rc_observe", one,
-                             (theta, params),
-                             in_axes=(0, 0), per_candidate=True,
-                             pad_rows=(None, self._pad_param_row))
+        with span("rc.observe"):
+            return self.exec.run(f"{self._ns}:rc_observe", one,
+                                 (theta, params),
+                                 in_axes=(0, 0), per_candidate=True,
+                                 pad_rows=(None, self._pad_param_row))
 
     @property
     def _implicit_steady(self):

@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels.fused_cg.ops import all_finite, record_fallback
+from ..runtime import span
 from ..testing import faults
 from .dss import family_zoh_simulate, zoh_discretize
 from .fidelity import (evict_stale_jits, register_family_fidelity,
@@ -413,16 +414,19 @@ class ROMModel:
     def simulate_batch(self, theta0, q_traj,
                        dt: Optional[float] = None) -> jnp.ndarray:
         """Batched rollout: theta0 (B, r), q_traj (T, B, S) ->
-        (T, B, n_obs) — one fused r x r GEMM per step for the batch."""
+        (T, B, n_obs) — one fused r x r GEMM per step for the batch.
+        Spans: ``rom.dispatch`` (``first=1`` when this call built the
+        closure) and ``rom.land``, the guard's read of the output."""
         dt = self.ts if dt is None else float(dt)
         key = ("simulate_batch", round(dt, 12))
-        if key not in self._jits:
+        first = key not in self._jits
+        if first:
             evict_stale_jits(self._jits, prefix="simulate_batch")
             ad, bd = self._zoh(dt)
             h, t_amb, dtype = self.H, self.t_ambient, self.dtype
 
             @jax.jit
-            def simulate(theta0, q_traj):
+            def rom_rollout(theta0, q_traj):
                 def body(th, qt):  # th (B, r), qt (B, S)
                     th = th @ ad.T + qt.astype(th.dtype) @ bd.T
                     return th, th @ h.T
@@ -430,10 +434,13 @@ class ROMModel:
                 _, obs = jax.lax.scan(body, theta0.astype(dtype), q_traj)
                 return obs + t_amb
 
-            self._jits[key] = simulate
-        out = self._jits[key](theta0, q_traj)
+            self._jits[key] = rom_rollout
+        with span("rom.dispatch", first=int(first)):
+            out = self._jits[key](theta0, q_traj)
         self.last_fallback = None
-        if not all_finite(faults.corrupt("rom.transient", out)):
+        with span("rom.land"):
+            finite = all_finite(faults.corrupt("rom.transient", out))
+        if not finite:
             # numerical guardrail: a poisoned/overflowed rollout (e.g.
             # f32 on a stiff pencil) promotes to the host-f64 exact-ZOH
             # reference rollout of the same reduced pencil

@@ -62,6 +62,8 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
+from ..runtime import span
+
 # dt-keyed jit entries (one XLA executable per sampling period) are
 # bounded to this many per key prefix, mirroring fidelity.evict_stale_jits
 _KEEP_JITS = 8
@@ -269,6 +271,10 @@ class FamilyExecutor:
         Returns the output with the pad tail sliced off: a device array
         for single-chunk runs, a host numpy array when chunk-streamed
         (that host landing is what bounds device memory to one chunk).
+
+        Spans: ``exec.run`` around ``exec.pad``, then per chunk
+        ``exec.dispatch`` (``first=1`` when this call built the jitted
+        closure) and, when streamed, ``exec.land``; then ``exec.concat``.
         """
         # coerce plain Python containers (lists/tuples) to host arrays;
         # real arrays pass through untouched so device arrays stay on
@@ -286,37 +292,46 @@ class FamilyExecutor:
             raise ValueError("run() needs at least one batched argument "
                              "with a non-empty candidate axis")
         b_pad, chunk = self._plan_batch(b)
-        padded = [a if ax is None else self._pad(a, ax, b, b_pad, row)
-                  for a, ax, row in zip(args, in_axes, pad_rows)]
-        jfn = self._compile(key, fn, in_axes, out_axis, per_candidate,
-                            make_carry is not None)
-
         n_chunks = b_pad // chunk
-        carry = make_carry(chunk) if make_carry is not None else None
-        outs = []
-        for c in range(n_chunks):
-            chunk_args = [a if ax is None
-                          else self._slice(a, ax, c * chunk, chunk)
-                          for a, ax in zip(padded, in_axes)]
-            if carry is not None:
-                out, carry = jfn(carry, *chunk_args)
-            else:
-                out = jfn(*chunk_args)
-            if n_chunks > 1:
-                # stream: device holds ONE chunk (leaf-wise for pytrees)
-                out = jax.tree_util.tree_map(np.asarray, out)
-            outs.append(out)
+        with span("exec.run"):
+            with span("exec.pad"):
+                padded = [a if ax is None
+                          else self._pad(a, ax, b, b_pad, row)
+                          for a, ax, row in zip(args, in_axes, pad_rows)]
+            built = key not in self._jits
+            jfn = self._compile(key, fn, in_axes, out_axis, per_candidate,
+                                make_carry is not None)
 
-        def unpad(tree):
-            return jax.tree_util.tree_map(
-                lambda leaf: self._slice(leaf, out_axis, 0, b), tree)
+            carry = make_carry(chunk) if make_carry is not None else None
+            outs = []
+            for c in range(n_chunks):
+                chunk_args = [a if ax is None
+                              else self._slice(a, ax, c * chunk, chunk)
+                              for a, ax in zip(padded, in_axes)]
+                with span("exec.dispatch", first=int(built and c == 0)):
+                    if carry is not None:
+                        out, carry = jfn(carry, *chunk_args)
+                    else:
+                        out = jfn(*chunk_args)
+                if n_chunks > 1:
+                    # stream: device holds ONE chunk (leaf-wise for
+                    # pytrees)
+                    with span("exec.land"):
+                        out = jax.tree_util.tree_map(np.asarray, out)
+                outs.append(out)
 
-        if n_chunks == 1:
-            out = outs[0]
-            return out if b_pad == b else unpad(out)
-        out = jax.tree_util.tree_map(
-            lambda *leaves: np.concatenate(leaves, axis=out_axis), *outs)
-        return out if b_pad == b else unpad(out)
+            def unpad(tree):
+                return jax.tree_util.tree_map(
+                    lambda leaf: self._slice(leaf, out_axis, 0, b), tree)
+
+            if n_chunks == 1:
+                out = outs[0]
+                return out if b_pad == b else unpad(out)
+            with span("exec.concat"):
+                out = jax.tree_util.tree_map(
+                    lambda *leaves: np.concatenate(leaves, axis=out_axis),
+                    *outs)
+                return out if b_pad == b else unpad(out)
 
     def run_value_and_grad(self, key, fn: Callable, args: Sequence,
                            in_axes: Sequence[Optional[int]],

@@ -25,7 +25,8 @@ execute callback. Because every group executes at the fixed capacity
 finishing request's slot is refilled from the queue on the next drain
 without recompilation. A full queue rejects at ``submit()`` time with a
 structured overflow response — backpressure, not an exception in the
-client thread.
+client thread. Spans: ``batcher.wait`` while the worker blocks on an
+empty queue, ``batcher.collect`` while it pops a group.
 
 Crash accounting (PR-9): the worker thread can die — an exception that
 escapes the execute callback, or an injected ``serving.worker`` fault.
@@ -45,6 +46,7 @@ import threading
 import time
 from typing import Callable, List, Optional
 
+from ..runtime import span
 from ..testing import faults
 
 
@@ -183,12 +185,15 @@ class ContinuousBatcher:
     def _loop(self) -> None:
         while True:
             with self._cond:
-                while not self._queue and not self._stop:
-                    self._cond.wait(timeout=0.5)
+                if not self._queue and not self._stop:
+                    with span("batcher.wait"):
+                        while not self._queue and not self._stop:
+                            self._cond.wait(timeout=0.5)
                 if self._stop:
                     return             # fail fast; stop() drains leftovers
-                group = self._collect()
-                self._inflight = group
+                with span("batcher.collect"):
+                    group = self._collect()
+                    self._inflight = group
             if group:
                 faults.fire("serving.worker")
                 self._execute(group[0].group_key, group)

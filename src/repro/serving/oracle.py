@@ -36,8 +36,13 @@ models, not one-shot ``build()`` scripts (cf. 3D-ICE 4.0's server mode).
 
   * **Telemetry.** Per-request latency, queue depth, batch occupancy,
     cache hit rate and CG stats land in ``serving/telemetry.py``'s ring
-    buffer; ``telemetry.snapshot()`` is the structured view the BENCH
-    ``serving`` section and the CI soak consume.
+    buffer; ``telemetry.snapshot()`` is its structured view, which the
+    soak scripts read. Beside it, profiler spans
+    (``repro.runtime.span``) time each stage on the device trace's
+    clock: ``oracle.submit`` (child ``cache.key``) on the caller's
+    thread; ``oracle.batch`` (``rows``, the requests it answers) with
+    children ``cache.get``, the model's own spans, ``oracle.land`` and
+    ``oracle.respond`` on the worker.
 
   * **Adaptive routing.** ``fidelity="auto"`` (default or per request)
     answers through the certified router (``core/router.py``): the
@@ -85,7 +90,7 @@ import numpy as np
 from ..core.dtpm import ThermalManager
 from ..core.fidelity import build, build_family
 from ..core.geometry import Package
-from ..runtime import x64
+from ..runtime import span, x64
 from ..testing import faults
 from .batcher import ContinuousBatcher
 from .cache import ModelCache
@@ -265,8 +270,9 @@ class ThermalOracle:
         return {**self.build_opts, **(opts or {})}
 
     def _key(self, target, fidelity: str, opts: dict) -> str:
-        return self.cache.key_for(target, fidelity, opts,
-                                  extra=("x64", self.x64))
+        with span("cache.key"):
+            return self.cache.key_for(target, fidelity, opts,
+                                      extra=("x64", self.x64))
 
     #: build opts that shape the ROM Krylov basis — everything else
     #: (ts, dtype, ...) reuses the same persisted basis.
@@ -303,9 +309,10 @@ class ThermalOracle:
         return model
 
     def _model(self, req: _Request) -> Tuple[object, bool, float]:
-        return self.cache.get_or_build(
-            req.key, lambda: self._build(req.target, req.fidelity,
-                                         req.opts))
+        with span("cache.get"):
+            return self.cache.get_or_build(
+                req.key, lambda: self._build(req.target, req.fidelity,
+                                             req.opts))
 
     def warm(self, target, fidelity: Optional[str] = None,
              **opts) -> Tuple[str, bool, float]:
@@ -373,28 +380,30 @@ class ThermalOracle:
     def submit_steady(self, pkg: Package, q, fidelity: Optional[str] = None,
                       opts: Optional[dict] = None,
                       deadline_s: Optional[float] = None) -> PendingResult:
-        fidelity = fidelity or self.fidelity
-        opts = self._opts(fidelity, opts)
-        key = self._key(pkg, fidelity, opts)
-        req = _Request("steady", key, pkg, fidelity, opts,
-                       {"q": np.asarray(q, np.float64)},
-                       group_key=(key, "steady"))
-        return self._submit(req, deadline_s)
+        with span("oracle.submit"):
+            fidelity = fidelity or self.fidelity
+            opts = self._opts(fidelity, opts)
+            key = self._key(pkg, fidelity, opts)
+            req = _Request("steady", key, pkg, fidelity, opts,
+                           {"q": np.asarray(q, np.float64)},
+                           group_key=(key, "steady"))
+            return self._submit(req, deadline_s)
 
     def submit_transient(self, pkg: Package, q_traj, dt: float,
                          fidelity: Optional[str] = None,
                          opts: Optional[dict] = None,
                          deadline_s: Optional[float] = None
                          ) -> PendingResult:
-        fidelity = fidelity or self.fidelity
-        opts = self._opts(fidelity, opts)
-        key = self._key(pkg, fidelity, opts)
-        q_traj = np.asarray(q_traj, np.float64)
-        req = _Request("transient", key, pkg, fidelity, opts,
-                       {"q_traj": q_traj, "dt": float(dt)},
-                       group_key=(key, "transient", q_traj.shape[0],
-                                  round(float(dt), 12)))
-        return self._submit(req, deadline_s)
+        with span("oracle.submit"):
+            fidelity = fidelity or self.fidelity
+            opts = self._opts(fidelity, opts)
+            key = self._key(pkg, fidelity, opts)
+            q_traj = np.asarray(q_traj, np.float64)
+            req = _Request("transient", key, pkg, fidelity, opts,
+                           {"q_traj": q_traj, "dt": float(dt)},
+                           group_key=(key, "transient", q_traj.shape[0],
+                                      round(float(dt), 12)))
+            return self._submit(req, deadline_s)
 
     def submit_dtpm(self, pkg: Package, powers_traj,
                     fidelity: Optional[str] = None,
@@ -410,16 +419,17 @@ class ThermalOracle:
             raise ValueError("DTPM requests need a state-space rung "
                              "('dss' or 'rom'), got "
                              f"fidelity={fidelity!r}")
-        opts = self._opts(fidelity, opts)
-        key = self._key(pkg, fidelity, opts)
-        powers_traj = np.asarray(powers_traj, np.float64)
-        ctrl = tuple(sorted((control or {}).items()))
-        req = _Request("dtpm", key, pkg, fidelity, opts,
-                       {"powers": powers_traj},
-                       group_key=(key, "dtpm", powers_traj.shape[0],
-                                  ctrl),
-                       control=ctrl)
-        return self._submit(req, deadline_s)
+        with span("oracle.submit"):
+            opts = self._opts(fidelity, opts)
+            key = self._key(pkg, fidelity, opts)
+            powers_traj = np.asarray(powers_traj, np.float64)
+            ctrl = tuple(sorted((control or {}).items()))
+            req = _Request("dtpm", key, pkg, fidelity, opts,
+                           {"powers": powers_traj},
+                           group_key=(key, "dtpm", powers_traj.shape[0],
+                                      ctrl),
+                           control=ctrl)
+            return self._submit(req, deadline_s)
 
     def submit_family_steady(self, family, params, q,
                              fidelity: Optional[str] = None,
@@ -430,30 +440,34 @@ class ThermalOracle:
         candidates against the same family coalesce into one
         ``steady_state_batch`` at the fixed capacity (pad =
         ``base_params()``)."""
-        fidelity = fidelity or self.fidelity
-        opts = self._opts(fidelity, opts)
-        key = self._key(family, fidelity, opts)
-        req = _Request("family_steady", key, family, fidelity, opts,
-                       {"params": np.asarray(params, np.float64),
-                        "q": np.asarray(q, np.float64)},
-                       group_key=(key, "family_steady"))
-        return self._submit(req, deadline_s)
+        with span("oracle.submit"):
+            fidelity = fidelity or self.fidelity
+            opts = self._opts(fidelity, opts)
+            key = self._key(family, fidelity, opts)
+            req = _Request("family_steady", key, family, fidelity, opts,
+                           {"params": np.asarray(params, np.float64),
+                            "q": np.asarray(q, np.float64)},
+                           group_key=(key, "family_steady"))
+            return self._submit(req, deadline_s)
 
     def submit_family_transient(self, family, params, q_traj, dt: float,
                                 fidelity: Optional[str] = None,
                                 opts: Optional[dict] = None,
                                 deadline_s: Optional[float] = None
                                 ) -> PendingResult:
-        fidelity = fidelity or self.fidelity
-        opts = self._opts(fidelity, opts)
-        key = self._key(family, fidelity, opts)
-        q_traj = np.asarray(q_traj, np.float64)
-        req = _Request("family_transient", key, family, fidelity, opts,
-                       {"params": np.asarray(params, np.float64),
-                        "q_traj": q_traj, "dt": float(dt)},
-                       group_key=(key, "family_transient",
-                                  q_traj.shape[0], round(float(dt), 12)))
-        return self._submit(req, deadline_s)
+        with span("oracle.submit"):
+            fidelity = fidelity or self.fidelity
+            opts = self._opts(fidelity, opts)
+            key = self._key(family, fidelity, opts)
+            q_traj = np.asarray(q_traj, np.float64)
+            req = _Request("family_transient", key, family, fidelity,
+                           opts,
+                           {"params": np.asarray(params, np.float64),
+                            "q_traj": q_traj, "dt": float(dt)},
+                           group_key=(key, "family_transient",
+                                      q_traj.shape[0],
+                                      round(float(dt), 12)))
+            return self._submit(req, deadline_s)
 
     # blocking conveniences -------------------------------------------------
     def query_steady(self, pkg, q, **kw) -> OracleResponse:
@@ -510,11 +524,12 @@ class ThermalOracle:
 
     def _execute(self, group_key: tuple, group) -> None:
         try:
-            if self.x64:
-                with x64():
+            with span("oracle.batch", rows=len(group)):
+                if self.x64:
+                    with x64():
+                        self._answer(group)
+                else:
                     self._answer(group)
-            else:
-                self._answer(group)
         except Exception as exc:  # noqa: BLE001 — service must stay live
             now = time.monotonic()
             frame = traceback.extract_tb(exc.__traceback__)[-1]
@@ -548,7 +563,7 @@ class ThermalOracle:
         faults.fire("serving.answer")   # chaos hook: batcher-side
         req0 = group[0].req             # exceptions / stalls mid-batch
         start = time.monotonic()
-        model, hit, build_s = self._model(req0)
+        model, hit, _ = self._model(req0)
         kind = req0.kind
         slot_routes: Optional[list] = None
         slot_fallbacks: Optional[list] = None
@@ -582,44 +597,44 @@ class ThermalOracle:
         degraded = cg is not None and not cg["converged"]
         done = time.monotonic()
         occupancy = len(group) / self.capacity
-        for i, (p, value) in enumerate(zip(group, values)):
-            info = None
-            if isinstance(value, tuple):   # dtpm: (trace, telemetry)
-                value, info = value
-            route = slot_routes[i] if i < len(slot_routes) else None
-            fallback = slot_fallbacks[i] \
-                if i < len(slot_fallbacks) else None
-            if degraded:
-                status = "degraded"
-                detail = ("CG hit its iteration cap — results may be "
-                          "unconverged (see cg)")
-            elif p.deadline is not None and done > p.deadline:
-                # the solve outlived the request's deadline mid-batch:
-                # honest timeout, value still attached for best-effort
-                # consumers
-                status = "timeout"
-                detail = (f"deadline passed mid-batch (answered "
-                          f"{done - p.deadline:.3f}s late; value "
-                          f"attached best-effort)")
-            elif p.retries > 0:
-                status = "retried"
-                detail = (f"answered after {p.retries} worker "
-                          f"restart(s) — see telemetry 'supervisor'")
-            else:
-                status, detail = "ok", ""
-            resp = OracleResponse(
-                status=status, value=value, detail=detail,
-                latency_s=done - p.enq_t, queue_s=start - p.enq_t,
-                cache_hit=hit, occupancy=occupancy, cg=cg, info=info,
-                route=route, retries=p.retries, fallback=fallback)
-            p.fulfill(resp)
-            self.telemetry.record(
-                kind=kind, status=resp.status, latency_s=resp.latency_s,
-                queue_s=resp.queue_s, queue_depth=p.queue_depth,
-                occupancy=occupancy, cache_hit=hit, cg=cg,
-                build_s=build_s,
-                **({"route": route} if route else {}),
-                **({"fallback": fallback} if fallback else {}))
+        with span("oracle.respond"):
+            for i, (p, value) in enumerate(zip(group, values)):
+                info = None
+                if isinstance(value, tuple):   # dtpm: (trace, telemetry)
+                    value, info = value
+                route = slot_routes[i] if i < len(slot_routes) else None
+                fallback = slot_fallbacks[i] \
+                    if i < len(slot_fallbacks) else None
+                if degraded:
+                    status = "degraded"
+                    detail = ("CG hit its iteration cap — results may be "
+                              "unconverged (see cg)")
+                elif p.deadline is not None and done > p.deadline:
+                    # the solve outlived the request's deadline mid-batch:
+                    # honest timeout, value still attached for best-effort
+                    # consumers
+                    status = "timeout"
+                    detail = (f"deadline passed mid-batch (answered "
+                              f"{done - p.deadline:.3f}s late; value "
+                              f"attached best-effort)")
+                elif p.retries > 0:
+                    status = "retried"
+                    detail = (f"answered after {p.retries} worker "
+                              f"restart(s) — see telemetry 'supervisor'")
+                else:
+                    status, detail = "ok", ""
+                resp = OracleResponse(
+                    status=status, value=value, detail=detail,
+                    latency_s=done - p.enq_t, queue_s=start - p.enq_t,
+                    cache_hit=hit, occupancy=occupancy, cg=cg, info=info,
+                    route=route, retries=p.retries, fallback=fallback)
+                p.fulfill(resp)
+                self.telemetry.record(
+                    kind=kind, status=resp.status, latency_s=resp.latency_s,
+                    queue_s=resp.queue_s, queue_depth=p.queue_depth,
+                    occupancy=occupancy, cache_hit=hit, cg=cg,
+                    **({"route": route} if route else {}),
+                    **({"fallback": fallback} if fallback else {}))
 
     @staticmethod
     def _routes_of(model, kind: str, n_slots: int) -> list:
@@ -646,7 +661,8 @@ class ThermalOracle:
             q[:, i, :] = p.req.payload["q_traj"]
         theta0 = model.zero_state(batch=self.capacity)
         obs = model.simulate_batch(theta0, q, dt)    # (T, capacity, O)
-        obs = np.asarray(obs)
+        with span("oracle.land"):
+            obs = np.asarray(obs)
         return [obs[:, i, :] for i in range(len(group))]
 
     def _answer_dtpm(self, model, group) -> list:
@@ -678,11 +694,15 @@ class ThermalOracle:
     def _answer_family_steady(self, model, group) -> list:
         params, q = self._family_batch(group, with_traj=False)
         theta = model.steady_state_batch(params, q)
-        temps = np.asarray(model.observe_batch(theta, params))
+        temps = model.observe_batch(theta, params)
+        with span("oracle.land"):
+            temps = np.asarray(temps)
         return [temps[i] for i in range(len(group))]
 
     def _answer_family_transient(self, model, group) -> list:
         params, q = self._family_batch(group, with_traj=True)
         dt = group[0].req.payload["dt"]
-        obs = np.asarray(model.simulate_family(params, q, dt))
+        obs = model.simulate_family(params, q, dt)
+        with span("oracle.land"):
+            obs = np.asarray(obs)
         return [obs[:, i, :] for i in range(len(group))]

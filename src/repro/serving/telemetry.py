@@ -9,13 +9,15 @@ came out of the warm cache, and — when the answering solver was the CG
 tier — a per-solve :class:`~repro.kernels.fused_cg.ops.CGStats` summary
 (max iterations, worst residual, all-converged flag).
 
-:meth:`Telemetry.snapshot` reduces the ring into the structured block
-the ``serving`` section of ``BENCH_exec_time.json`` consumes: request
-counts by status/kind, p50/p99 latency per request kind, mean queue
-depth and batch occupancy, the attached cache's hit/miss/byte stats,
-and the process-wide per-site unconverged-CG counters that the
+:meth:`Telemetry.snapshot` reduces the ring into a structured block:
+request counts by status/kind, p50/p99 latency per request kind, mean
+queue depth and batch occupancy, the attached cache's hit/miss/byte
+stats, and the process-wide per-site unconverged-CG counters that the
 rate-limited ``warn_unconverged`` accumulates
-(``kernels/fused_cg/ops.unconverged_counts``).
+(``kernels/fused_cg/ops.unconverged_counts``). The soak scripts read
+it; ``benchmarks/exec_time.py`` takes only its cache and status counts.
+The timeline of a request's stages is not kept here: the profiler spans
+of ``repro.runtime.span`` carry it, on the device trace's clock.
 
 Requests answered through the adaptive fidelity router
 (``fidelity="auto"``, ``core/router.py``) additionally land a ``route``
@@ -93,7 +95,7 @@ class Telemetry:
 
     # ------------------------------------------------------------------
     def snapshot(self) -> dict:
-        """Structured reduction of the ring (the BENCH-consumed shape)."""
+        """Structured reduction of the ring (module docstring)."""
         from ..kernels.fused_cg.ops import fallback_counts, \
             unconverged_counts
         with self._lock:

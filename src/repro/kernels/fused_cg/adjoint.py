@@ -41,7 +41,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kernel import SUBLANE
 from .ops import (CGStats, FusedCGPlan, _offdiag_segsum, fused_cg_solve,
                   warn_unconverged)
 
@@ -123,7 +122,6 @@ def adjoint_offdiag_matvec(plan: FusedCGPlan, gvals, x):
 
 def make_implicit_steady(plan: FusedCGPlan, *, tol: float, maxiter: int,
                          impl: str = "auto", backend: str = "auto",
-                         block_b: int = SUBLANE,
                          site: str = "implicit steady adjoint CG"):
     """Build a reverse-differentiable matrix-free steady solver.
 
@@ -148,8 +146,7 @@ def make_implicit_steady(plan: FusedCGPlan, *, tol: float, maxiter: int,
 
     def _solve(diag, gvals, rhs):
         return fused_cg_solve(plan, diag, gvals, rhs, tol=tol,
-                              maxiter=maxiter, impl=impl, backend=backend,
-                              block_b=block_b)
+                              maxiter=maxiter, impl=impl, backend=backend)
 
     @jax.custom_vjp
     def solve(diag, gvals, rhs):

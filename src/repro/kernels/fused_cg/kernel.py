@@ -28,9 +28,17 @@ single launch, flash-attention style (see ``kernels/flash_attn``):
   * the scalar CG state (rho = r·z, ||r||^2, per-row iteration counts)
     rides (B, 1) operands through the launch, so the OUTER ``while_loop``
     body is exactly one kernel call plus a convergence check on ||r||^2;
+  * x, r, p and the scalar state are aliased to their outputs: a grid
+    step reads and writes only its own batch rows, so the launch updates
+    the ``while_loop`` carry in place, and XLA copies no state between
+    iterations;
   * the batch axis rides the GEMM sublane dimension as in ``coo_matvec``,
     so the family solvers need no vmap, and per-row live masks replicate
-    the masked-batch semantics of the unfused loop bit for bit.
+    the masked-batch semantics of the unfused loop bit for bit. The
+    batch block is sized to the batch and to VMEM (``ops.fused_cg_block``):
+    the one-hot tiles are built once per grid step and the MXU loads each
+    weight tile once per step, so a taller block serves more rows with
+    both.
 
 The masking formulas are EXACTLY those of the unfused reference loop
 (``ops.pcg_loop``): a row is live while ``||r||^2 > tol^2 ||b||^2``;
@@ -127,15 +135,17 @@ def _cg_step_kernel(colbase_ref, rows_ref, cols_ref, gv_ref, diag_ref,
 
 def fused_cg_step_pallas(colbase, rows2d, cols2d, gvals, diag, x, r, p,
                          rz, rn2, it, tol2, *, row_span: int,
-                         col_span: int, be: int, block_b: int = SUBLANE,
-                         interpret: bool = False):
+                         col_span: int, be: int, block_b: int,
+                         vmem_limit_bytes: int, interpret: bool = False):
     """One fused Jacobi-PCG iteration on pre-padded operands.
 
     colbase (n_tiles,) int32 lane-aligned window starts; rows2d /
     cols2d (e_pad, 1) int32 (rows absolute sorted, cols relative);
     gvals (b_pad, e_pad) zero-padded; diag/x/r/p (b_pad, n_pad) with
     ``diag`` one-padded; rz/rn2/tol2 (b_pad, 1); it (b_pad, 1) int32.
-    Returns (x', r', p', rz', rn2', it').
+    ``block_b`` rows of the batch share one grid step; the caller sizes
+    it and the scoped-VMEM limit (``ops.fused_cg_block``). Returns
+    (x', r', p', rz', rn2', it').
     """
     b_pad, e_pad = gvals.shape
     n_pad = x.shape[1]
@@ -181,7 +191,10 @@ def fused_cg_step_pallas(colbase, rows2d, cols2d, gvals, diag, x, r, p,
             jax.ShapeDtypeStruct((b_pad, 1), jnp.int32),      # it'
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes),
+        # x, r, p, rz, rn2, it (operands 5-10, colbase counted) -> outputs
+        input_output_aliases={5: 0, 6: 1, 7: 2, 8: 3, 9: 4, 10: 5},
         interpret=interpret,
         name="fused_cg_step",
     )(colbase, rows2d, cols2d, gvals, diag, x, r, p, rz, rn2, it, tol2)

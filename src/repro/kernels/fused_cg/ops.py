@@ -53,7 +53,8 @@ from .kernel import LANE, SUBLANE, fused_cg_step_pallas
 
 __all__ = [
     "CGStats", "FusedCGPlan", "all_finite", "fallback_counts",
-    "fused_cg_plan", "fused_cg_solve", "pcg_loop", "record_fallback",
+    "fused_cg_block", "fused_cg_plan", "fused_cg_solve",
+    "fused_cg_vmem_bytes", "pcg_loop", "record_fallback",
     "resolve_cg_impl", "warn_unconverged", "unconverged_counts",
     "reset_unconverged_counts",
 ]
@@ -223,6 +224,50 @@ def _freeze_plan(n, e, block_edges, row_span, col_span, n_pad, e_pad,
     )
 
 
+# Largest batch block of the fused kernel: two passes of the v5e's
+# 128-row MXU, so every one-hot tile built and every weight load serves
+# up to 256 rows. On a v5e at B = 2048, 256 ran the kernel 2-5% faster
+# than 128 on the Table-6 sweep plans (PERF.md, section 6).
+BLOCK_CAP = 256
+# VMEM the block's buffers may take (``fused_cg_vmem_bytes``). The limit
+# handed to the compiler adds room for the epilogue's temporaries, never
+# goes below the compiler's default scoped limit (16 MiB on v5e) and stays
+# well inside the v5e core's 128 MiB.
+VMEM_BUDGET = 48 << 20
+_VMEM_MARGIN = 1.5
+_VMEM_FLOOR = 16 << 20
+_VMEM_CEIL = 100 << 20
+
+
+def fused_cg_vmem_bytes(block: int, plan: FusedCGPlan, itemsize: int) -> int:
+    """VMEM bytes of one fused-CG launch at batch block ``block``: the
+    seven double-buffered (block, n_pad) state blocks (diag, x, r, p in;
+    x, r, p out), the f32 ``ap`` scratch, the double-buffered gvals tile
+    and the gather and scatter one-hot temporaries."""
+    be = plan.block_edges
+    state = 7 * 2 * block * plan.n_pad * itemsize
+    ap = block * plan.n_pad * max(itemsize, 4)
+    gvals = 2 * block * be * itemsize
+    onehots = be * (plan.col_span + plan.row_span) * itemsize
+    return state + ap + gvals + onehots
+
+
+def fused_cg_block(b: int, plan: FusedCGPlan, itemsize: int) -> tuple:
+    """``(block, vmem_limit_bytes)`` of the fused kernel for a batch of
+    ``b`` rows: the largest multiple of SUBLANE that divides
+    ``round_up(b, SUBLANE)``, is at most ``BLOCK_CAP`` and whose
+    footprint fits ``VMEM_BUDGET`` (SUBLANE at the least). Dividing,
+    not padding, computes no extra rows; b <= SUBLANE gives SUBLANE."""
+    b_pad = _round_up(b, SUBLANE)
+    block = max(
+        (r for r in range(SUBLANE, min(b_pad, BLOCK_CAP) + 1, SUBLANE)
+         if b_pad % r == 0
+         and fused_cg_vmem_bytes(r, plan, itemsize) <= VMEM_BUDGET),
+        default=SUBLANE)
+    need = int(_VMEM_MARGIN * fused_cg_vmem_bytes(block, plan, itemsize))
+    return block, min(max(need, _VMEM_FLOOR), _VMEM_CEIL)
+
+
 # --------------------------------------------------------------------------
 # matvec forms (all in the plan's permuted node space)
 
@@ -259,7 +304,7 @@ def _offdiag_coo_kernel(plan: FusedCGPlan, gv_sorted: jnp.ndarray,
 
 
 def _solve2d(plan: FusedCGPlan, diag, gvals, rhs, x0, *, tol, maxiter,
-             impl, backend, block_b):
+             impl, backend):
     """Batched Jacobi PCG on (B, N) operands in permuted space."""
     dtype = rhs.dtype
     b, n = rhs.shape
@@ -290,6 +335,7 @@ def _solve2d(plan: FusedCGPlan, diag, gvals, rhs, x0, *, tol, maxiter,
     it0 = jnp.zeros((b,), jnp.int32)
 
     if use_pallas:
+        block_b, vmem_limit = fused_cg_block(b, plan, dtype.itemsize)
         b_pad = _round_up(b, block_b)
         n_pad = plan.n_pad
 
@@ -312,6 +358,7 @@ def _solve2d(plan: FusedCGPlan, diag, gvals, rhs, x0, *, tol, maxiter,
                 x, r, p, rz, rn2, itr, tol_p,
                 row_span=plan.row_span, col_span=plan.col_span,
                 be=plan.block_edges, block_b=block_b,
+                vmem_limit_bytes=vmem_limit,
                 interpret=backend == "interpret")
 
         def cond(s):
@@ -365,7 +412,7 @@ def _solve2d(plan: FusedCGPlan, diag, gvals, rhs, x0, *, tol, maxiter,
 
 def fused_cg_solve(plan: FusedCGPlan, diag, gvals, rhs, x0=None, *,
                    tol: float, maxiter: int, impl: str = "auto",
-                   backend: str = "auto", block_b: int = SUBLANE):
+                   backend: str = "auto"):
     """Solve ``(diag(diag) - offdiag(gvals)) x = rhs`` by Jacobi PCG.
 
     diag (..., N) positive; gvals (..., E) POSITIVE pairwise conductances
@@ -399,7 +446,7 @@ def fused_cg_solve(plan: FusedCGPlan, diag, gvals, rhs, x0=None, *,
     # reshape((-1, 0)) is ill-posed, so size the empty-edge case off b2
     g2 = flat(gvals, e) if e else jnp.zeros((b2.shape[0], 0), dtype)
     xp, stats = _solve2d(plan, d2, g2, b2, x02, tol=tol, maxiter=maxiter,
-                         impl=impl, backend=backend, block_b=block_b)
+                         impl=impl, backend=backend)
     x = xp[:, plan.node_inv].reshape(lead + (n,))
     return x, CGStats(*(s.reshape(lead) for s in stats))
 

@@ -10,7 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import ThermalRCModel, build_network, make_2p5d_package
+from repro.core import (ThermalRCModel, build_network, make_2p5d_package,
+                        package_from_name)
 from repro.kernels.fused_cg import ops
 from repro.kernels.fused_cg.ops import (fused_cg_plan, fused_cg_solve,
                                         pcg_loop, resolve_cg_impl)
@@ -225,6 +226,64 @@ def test_pcg_loop_matches_fused_jacobi():
     assert np.asarray(stf.converged).all() and \
         np.asarray(stg.converged).all()
     np.testing.assert_allclose(np.asarray(xf), np.asarray(xg), atol=1e-7)
+
+
+@pytest.fixture(scope="module")
+def table6_plans():
+    return {name: fused_cg_plan(net.rows, net.cols, net.n)
+            for name in ("2p5d_64", "3d_16x3", "2p5d_256")
+            for net in [build_network(package_from_name(name)[0])]}
+
+
+@pytest.mark.parametrize("name,sweep_block", [
+    ("2p5d_64", 256), ("3d_16x3", 256), ("2p5d_256", 64)])
+def test_batch_block_rule(table6_plans, name, sweep_block):
+    """The batch block is the largest multiple of SUBLANE that divides the
+    batch padded to SUBLANE, within the cap and the VMEM budget; it is
+    SUBLANE for batches of at most SUBLANE rows (the program single
+    solves ran before the rule)."""
+    plan = table6_plans[name]
+    for b in range(1, ops.SUBLANE + 1):
+        assert ops.fused_cg_block(b, plan, 4)[0] == ops.SUBLANE
+    for b in (9, 11, 48, 100, 1000, 2048, 4096, 10000):
+        block, limit = ops.fused_cg_block(b, plan, 4)
+        b_pad = -(-b // ops.SUBLANE) * ops.SUBLANE
+        assert block % ops.SUBLANE == 0 and b_pad % block == 0, (b, block)
+        assert block <= ops.BLOCK_CAP
+        need = ops.fused_cg_vmem_bytes(block, plan, 4)
+        assert need <= ops.VMEM_BUDGET
+        assert need < limit <= ops._VMEM_CEIL
+        # the largest such block: the next divisor up is over the cap or
+        # the budget
+        bigger = [r for r in range(block + ops.SUBLANE, b_pad + 1,
+                                   ops.SUBLANE) if b_pad % r == 0]
+        if bigger:
+            assert (bigger[0] > ops.BLOCK_CAP or ops.fused_cg_vmem_bytes(
+                bigger[0], plan, 4) > ops.VMEM_BUDGET), (b, block)
+    assert ops.fused_cg_block(2048, plan, 4)[0] == sweep_block
+
+
+def test_wide_block_parity_and_iterations():
+    """A batch whose block exceeds SUBLANE (the kernel in interpret mode)
+    matches the dense f64 oracle, and every row spends the iterations it
+    spends on the fused XLA path."""
+    n, e, b = 129, 513, 48
+    rows, cols, gvals, diag = random_spd_system(n, e, seed=21)
+    rhs = np.random.default_rng(5).normal(size=(b, n))
+    ref = dense_solve_ref(diag, gvals, rows, cols, rhs)
+    with jax.enable_x64(True):
+        plan = fused_cg_plan(rows, cols, n)
+        assert ops.fused_cg_block(b, plan, 8)[0] > ops.SUBLANE
+        out = {backend: fused_cg_solve(plan, jnp.asarray(diag),
+                                       jnp.asarray(gvals), jnp.asarray(rhs),
+                                       tol=1e-12, maxiter=4 * n,
+                                       impl="fused", backend=backend)
+               for backend in ("interpret", "xla")}
+    x, stats = out["interpret"]
+    assert np.asarray(stats.converged).all()
+    np.testing.assert_allclose(np.asarray(x), ref, atol=1e-8)
+    np.testing.assert_array_equal(np.asarray(stats.iterations),
+                                  np.asarray(out["xla"][1].iterations))
 
 
 def test_resolve_cg_impl():

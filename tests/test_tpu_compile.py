@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import make_2p5d_package
+from repro.core import make_2p5d_package, package_from_name
 from repro.core.rc_model import build_network
 from repro.kernels.backend import resolve_backend
 from repro.kernels.coo_matvec.ops import coo_matvec, coo_plan
@@ -46,7 +46,9 @@ def one_chip():
 
 @pytest.fixture(scope="module")
 def nets():
-    return {n: build_network(make_2p5d_package(n)) for n in (64, 256)}
+    out = {n: build_network(make_2p5d_package(n)) for n in (64, 256)}
+    out["3d_16x3"] = build_network(package_from_name("3d_16x3")[0])
+    return out
 
 
 def _spec(shape, dtype, sharding):
@@ -58,7 +60,10 @@ def _has_kernel(compiled, name: str) -> bool:
     return "tpu_custom_call" in text and name in text
 
 
-@pytest.mark.parametrize("chiplets,batch", [(64, 1), (64, 64), (256, 8)])
+# the sweep's chunk of 2048 rows (a batch block above 8 rows) on both
+# sweep patterns, and a batch whose block is not a power of two
+@pytest.mark.parametrize("chiplets,batch", [
+    (64, 1), (64, 64), (256, 8), (64, 2048), ("3d_16x3", 2048), (64, 1000)])
 def test_fused_cg_compiles_for_v5e(one_chip, nets, chiplets, batch):
     net = nets[chiplets]
     plan = fused_cg_plan(net.rows, net.cols, net.n)

@@ -103,7 +103,7 @@ shard.observe_batch(shard.steady_state_batch(params10[:CHUNK],
                                              q10[:CHUNK]),
                     params10[:CHUNK])
 t0 = time.time()
-th10 = shard.steady_state_batch(params10, q10)      # streams to host
+th10 = shard.steady_state_batch(params10, q10)      # stays on device
 temps10 = np.asarray(shard.observe_batch(th10, params10))
 dt_shard = time.time() - t0
 peak10 = temps10.max(axis=1)

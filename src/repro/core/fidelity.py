@@ -36,10 +36,11 @@ fidelity routes its candidate batch through a
 ``(B, P)`` axis across the mesh's ``data`` axis via ``shard_map`` —
 candidates are independent, so sweeps scale with device count with zero
 collectives (non-divisible B is padded with the template candidate and
-sliced off). ``chunk_size=`` streams larger-than-memory sweeps over
-fixed-size candidate chunks, landing each chunk's result in host memory
-(the RC steady CG warm-starts each chunk from the previous one). The
-``sharded_dse`` section of ``BENCH_exec_time.json`` tracks both.
+sliced off). ``chunk_size=`` streams sweeps over fixed-size candidate
+chunks, joined on the device when the sweep's output fits a share of
+device memory, else landed in host memory chunk by chunk (the RC steady
+CG warm-starts each chunk from the previous one). The ``sharded_dse``
+section of ``BENCH_exec_time.json`` tracks both.
 
 ``build(pkg, fid)`` is the degenerate single-element case of the family
 API: a family whose parameter set is empty pins the template, and the

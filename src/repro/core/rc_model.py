@@ -972,9 +972,10 @@ class RCFamilyModel:
         pass instead of an unrolled ``while_loop``. Executor-routed, so
         candidate batches shard over the mesh like any sweep (for
         chunk-streamed or padded batches take gradients through
-        :meth:`peak_steady_and_grad`, whose padding is masked on the
-        host — tracing ``jax.grad`` through a chunked ``run()`` would
-        hit the host landing). Softmax-free: the true max.
+        :meth:`peak_steady_and_grad`, whose padding is masked — a
+        chunked ``run()`` whose output does not fit on the device lands
+        it on the host chunk by chunk, which ``jax.grad`` cannot trace
+        through). Softmax-free: the true max.
         """
         # q pad rows are ones, not zeros: a zero rhs makes the relative CG
         # residual 0/0 and trips warn_unconverged for rows that are

@@ -28,14 +28,24 @@ caller-provided pad row (family models pad with the template's
 ``base_params()``, a valid candidate, so padding can never produce
 degenerate geometry) and the tail is sliced off the result.
 
-**Chunk streaming.** Sweeps larger than memory run as a host-side scan
-over fixed-size candidate chunks (``chunk_size=``): one compiled
-executable is reused for every chunk, each chunk's result is pulled to
-host memory before the next chunk is dispatched (device footprint is one
-chunk, not one sweep), and call sites that solve iteratively can thread a
-carry between chunks — the RC family's steady CG warm-starts each chunk
-from the previous chunk's converged states, which is what makes a B=10k
-steady sweep both bounded-memory and cheaper than 20 cold B=512 sweeps.
+**Chunk streaming.** Sweeps run as a host-side scan over fixed-size
+candidate chunks (``chunk_size=``): one compiled executable is reused for
+every chunk, and call sites that solve iteratively can thread a carry
+between chunks — the RC family's steady CG warm-starts each chunk from the
+previous chunk's converged states, which is what makes a B=10k steady
+sweep cheaper than 20 cold B=512 sweeps. Where the chunks' results live
+follows one fit rule, decided before the first chunk is dispatched: when
+the whole padded output takes at most ``_RESIDENT_SHARE`` of one device's
+memory (its bytes per device, from the chunk program's output shapes, ×
+chunks ÷ shards, against ``memory_stats()["bytes_limit"]``; a backend that
+reports no memory, as the CPU, always fits), every chunk's output stays on
+the device, the dispatches queue back to back with no host sync between
+them, and the chunks are joined on the device. A device array passed back
+in (the observe's ``theta``) is padded and cut into chunks on the device
+too, so a steady solve's states never cross to the host between the solve
+and the observe. An output that does not fit lands on the host chunk by
+chunk, so the device holds one chunk of it at a time: sweeps larger than
+memory keep that bound.
 
 The two compose: ``chunk_size`` must be a multiple of the shard count and
 each chunk is itself mesh-sharded. CPU CI exercises the mesh path with
@@ -53,13 +63,15 @@ the candidate batch.
 """
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax import lax
+from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..runtime import span
@@ -67,6 +79,52 @@ from ..runtime import span
 # dt-keyed jit entries (one XLA executable per sampling period) are
 # bounded to this many per key prefix, mirroring fidelity.evict_stale_jits
 _KEEP_JITS = 8
+
+# share of one device's memory a streamed call's joined output may take
+# and still stay on the device: a quarter leaves room for the next call's
+# inputs and a chunk program's temporaries (an FVM chunk of 64 candidates
+# at 0.25 mm takes 555 MiB of them)
+_RESIDENT_SHARE = 0.25
+
+
+# The device-side helpers of a streamed call are module-level jits: one
+# program per (helper, shapes, static arguments), shared by every
+# executor; none is keyed on a chunk index. ``sharding`` (None off the
+# mesh) places each chunk batch-sharded over the mesh, as the chunk
+# programs take and give their arrays.
+def _placed(x, sharding):
+    return x if sharding is None else \
+        lax.with_sharding_constraint(x, sharding)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
+def _split(arr, pad_row, axis: int, n_chunks: int, chunk: int, sharding):
+    """``arr`` padded along ``axis`` to ``n_chunks * chunk`` rows with
+    ``pad_row`` (one batch element, broadcast) and cut into its chunks,
+    all in one program. On the mesh XLA then moves only the rows each
+    chunk's shards lack (collective permutes); a chunk cut from the
+    batch-sharded array by its own call would all-gather the array."""
+    b = arr.shape[axis]
+    if n_chunks * chunk > b:
+        shape = list(arr.shape)
+        shape[axis] = n_chunks * chunk - b
+        if pad_row.ndim:
+            pad_row = jnp.expand_dims(pad_row, axis)
+        arr = jnp.concatenate([arr, jnp.broadcast_to(pad_row, shape)],
+                              axis=axis)
+    return tuple(_placed(lax.slice_in_dim(arr, c * chunk, (c + 1) * chunk,
+                                          axis=axis), sharding)
+                 for c in range(n_chunks))
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _join(outs, axis: int, b: int, sharding):
+    """Chunk outputs (a list of like pytrees) joined leaf-wise along
+    ``axis``, with the pad tail past row ``b`` sliced off."""
+    def leaf(*chunks):
+        return _placed(lax.slice_in_dim(jnp.concatenate(chunks, axis), 0,
+                                        b, axis=axis), sharding)
+    return jax.tree_util.tree_map(leaf, *outs)
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
@@ -87,9 +145,10 @@ class FamilyExecutor:
                  (the first k host devices, ``launch.mesh.make_host_mesh``).
                  The candidate axis shards over ``batch_axis``.
     chunk_size:  None (whole batch in one device call) | int: sweeps with
-                 ``B > chunk_size`` stream over fixed-size chunks, results
-                 land in host memory chunk by chunk. Must be a multiple of
-                 the shard count.
+                 ``B > chunk_size`` stream over fixed-size chunks, joined
+                 on the device when the output fits, else landed in host
+                 memory chunk by chunk. Must be a multiple of the shard
+                 count.
     batch_axis:  name of the mesh axis carrying the candidate batch.
     """
 
@@ -162,39 +221,96 @@ class FamilyExecutor:
         b_pad = -(-b // chunk) * chunk
         return b_pad, chunk
 
-    @staticmethod
-    def _pad(arr, axis: int, b: int, b_pad: int, pad_row):
-        """Pad ``arr`` along ``axis`` from b to b_pad rows (host numpy).
-
-        ``pad_row`` is one batch element with the batch axis removed
-        (e.g. the family's ``base_params()``) repeated into the tail, or
-        None for zeros. Device arrays are padded with device ops —
-        pulling e.g. a whole (B, N) sharded state to the host just to
-        append a few pad rows would cost a D2H+H2D round-trip of the
-        entire batch on every call."""
+    def _pad(self, arr, axis: Optional[int], pad_row, b: int,
+             n_chunks: int, chunk: int):
+        """``arr`` made ready to cut into ``n_chunks`` chunks of ``chunk``
+        rows along ``axis``. ``pad_row`` is one batch element with the
+        batch axis removed (e.g. the family's ``base_params()``) repeated
+        into the tail, or None for zeros. Host arrays are padded on the
+        host. Device arrays are padded and cut on the device by
+        ``_split`` (a tuple of chunks): pulling a whole (B, N) state to
+        the host just to append a few pad rows would cost a round-trip
+        of the batch."""
+        b_pad = n_chunks * chunk
+        if axis is None or (b_pad == b and n_chunks == 1):
+            return arr
+        row = np.asarray(pad_row if pad_row is not None else 0, arr.dtype)
+        if isinstance(arr, jax.Array):
+            return _split(arr, row, axis, n_chunks, chunk,
+                          self._sharding(axis))
         if b_pad == b:
-            return arr  # no pad: hand through (device arrays stay put)
-        xp = jnp if isinstance(arr, jax.Array) else np
-        arr = xp.asarray(arr)
+            return arr
         shape = list(arr.shape)
         shape[axis] = b_pad - b
-        if pad_row is None:
-            tail = xp.zeros(shape, arr.dtype)
-        else:
-            tail = xp.broadcast_to(
-                xp.expand_dims(xp.asarray(pad_row, arr.dtype), axis),
-                shape)
-        return xp.concatenate([arr, tail], axis=axis)
+        tail = np.broadcast_to(np.expand_dims(row, axis) if row.ndim
+                               else row, shape)
+        return np.concatenate([arr, tail], axis=axis)
 
     @staticmethod
     def _slice(arr, axis: int, start: int, length: int):
         sl = (slice(None),) * axis + (slice(start, start + length),)
         return arr[sl]
 
+    def _chunk(self, arr, axis: Optional[int], c: int, chunk: int):
+        """Chunk ``c`` of an argument ``_pad`` made ready."""
+        if isinstance(arr, tuple):
+            return arr[c]               # cut on the device
+        if axis is None or isinstance(arr, jax.Array):
+            return arr                  # not batched, or one whole chunk
+        return self._slice(arr, axis, c * chunk, chunk)
+
     def _spec(self, axis: Optional[int]) -> P:
         if axis is None:
             return P()
         return P(*((None,) * axis), self.batch_axis)
+
+    def _sharding(self, axis: int) -> Optional[NamedSharding]:
+        """Batch-sharded placement over the mesh; None off the mesh."""
+        if self.mesh is None:
+            return None
+        return NamedSharding(self.mesh, self._spec(axis))
+
+    # ------------------------------------------------------------------
+    # the fit rule
+    # ------------------------------------------------------------------
+    def _budget(self) -> Optional[float]:
+        """Bytes of one device's memory a streamed call's joined output
+        may take on the device: ``_RESIDENT_SHARE`` of its
+        ``bytes_limit``. None where the backend reports no memory (the
+        CPU, whose device memory is the host's)."""
+        dev = self.mesh.devices.flat[0] if self.mesh is not None \
+            else jax.devices()[0]
+        stats = dev.memory_stats()
+        if not stats or "bytes_limit" not in stats:
+            return None
+        return _RESIDENT_SHARE * stats["bytes_limit"]
+
+    def _fits(self, jfn, args, in_axes, chunk: int, n_chunks: int,
+              make_carry) -> bool:
+        """Whether the call's padded output, per device, fits the budget:
+        the chunk program's output bytes (traced shapes, no run) × chunks
+        ÷ shards."""
+        budget = self._budget()
+        if budget is None:
+            return True
+
+        def at_chunk(a, ax):
+            if ax is None:
+                return a
+            shape = list(np.shape(a))
+            shape[ax] = chunk
+            return jax.ShapeDtypeStruct(
+                shape, jax.dtypes.canonicalize_dtype(a.dtype))
+
+        structs = [at_chunk(a, ax) for a, ax in zip(args, in_axes)]
+        if make_carry is not None:
+            carry = jax.eval_shape(lambda: make_carry(chunk))
+            out = jfn.eval_shape(carry, *structs)[0]
+        else:
+            out = jfn.eval_shape(*structs)
+        nbytes = sum(leaf.size * leaf.dtype.itemsize
+                     for leaf in jax.tree_util.tree_leaves(out))
+        return nbytes * n_chunks / self.n_shards <= budget
 
     # ------------------------------------------------------------------
     # jit cache
@@ -268,13 +384,20 @@ class FamilyExecutor:
                        pad candidates stay valid geometry.
         make_carry:    chunk length -> initial carry.
 
-        Returns the output with the pad tail sliced off: a device array
-        for single-chunk runs, a host numpy array when chunk-streamed
-        (that host landing is what bounds device memory to one chunk).
+        Returns the output with the pad tail sliced off. It stays on the
+        device (batch-sharded on the mesh) for single-chunk runs and for
+        streamed runs whose padded output fits ``_RESIDENT_SHARE`` of a
+        device's memory (:meth:`_fits`); a streamed output that does not
+        fit lands on the host chunk by chunk and returns as numpy, which
+        bounds the device's share of it to one chunk.
 
-        Spans: ``exec.run`` around ``exec.pad``, then per chunk
-        ``exec.dispatch`` (``first=1`` when this call built the jitted
-        closure) and, when streamed, ``exec.land``; then ``exec.concat``.
+        Spans: ``exec.run`` [``chunks``, ``resident``: the chunk count and
+        whether the output stays on the device, both decided before the
+        span opens] around ``exec.pad`` (host arguments padded, device
+        ones padded and cut), then per chunk ``exec.dispatch``
+        (``first=1`` when this call built the jitted closure) and, when
+        landed, ``exec.land``; then, when streamed, ``exec.concat`` (the
+        join, on the device or on the host).
         """
         # coerce plain Python containers (lists/tuples) to host arrays;
         # real arrays pass through untouched so device arrays stay on
@@ -293,29 +416,28 @@ class FamilyExecutor:
                              "with a non-empty candidate axis")
         b_pad, chunk = self._plan_batch(b)
         n_chunks = b_pad // chunk
-        with span("exec.run"):
+        built = key not in self._jits
+        jfn = self._compile(key, fn, in_axes, out_axis, per_candidate,
+                            make_carry is not None)
+        resident = n_chunks == 1 or self._fits(jfn, args, in_axes, chunk,
+                                               n_chunks, make_carry)
+        with span("exec.run", chunks=n_chunks, resident=int(resident)):
             with span("exec.pad"):
-                padded = [a if ax is None
-                          else self._pad(a, ax, b, b_pad, row)
+                padded = [self._pad(a, ax, row, b, n_chunks, chunk)
                           for a, ax, row in zip(args, in_axes, pad_rows)]
-            built = key not in self._jits
-            jfn = self._compile(key, fn, in_axes, out_axis, per_candidate,
-                                make_carry is not None)
 
             carry = make_carry(chunk) if make_carry is not None else None
             outs = []
             for c in range(n_chunks):
-                chunk_args = [a if ax is None
-                              else self._slice(a, ax, c * chunk, chunk)
+                chunk_args = [self._chunk(a, ax, c, chunk)
                               for a, ax in zip(padded, in_axes)]
                 with span("exec.dispatch", first=int(built and c == 0)):
                     if carry is not None:
                         out, carry = jfn(carry, *chunk_args)
                     else:
                         out = jfn(*chunk_args)
-                if n_chunks > 1:
-                    # stream: device holds ONE chunk (leaf-wise for
-                    # pytrees)
+                if not resident:
+                    # the device holds ONE chunk (leaf-wise for pytrees)
                     with span("exec.land"):
                         out = jax.tree_util.tree_map(np.asarray, out)
                 outs.append(out)
@@ -328,6 +450,9 @@ class FamilyExecutor:
                 out = outs[0]
                 return out if b_pad == b else unpad(out)
             with span("exec.concat"):
+                if resident:
+                    return _join(outs, out_axis, b,
+                                 self._sharding(out_axis))
                 out = jax.tree_util.tree_map(
                     lambda *leaves: np.concatenate(leaves, axis=out_axis),
                     *outs)
@@ -350,8 +475,8 @@ class FamilyExecutor:
         each row's value/grad is independent of every other row, and the
         pad tail is sliced off before returning — a padded start can never
         contaminate a real candidate's objective or gradient. Chunked
-        batches land on the host per chunk exactly like :meth:`run`
-        (optimizer loops consume host values anyway)."""
+        batches follow :meth:`run`'s fit rule: joined on the device when
+        they fit, else landed on the host per chunk."""
         vg = jax.value_and_grad(fn, argnums=argnums)
         return self.run(key, vg, args, in_axes=in_axes, out_axis=0,
                         per_candidate=True, pad_rows=pad_rows)

@@ -8,6 +8,7 @@ plain single-device session). ``test_sharded_families_subprocess`` keeps
 the 8-device acceptance check in tier-1 regardless, by spawning a fresh
 interpreter with the flag set.
 """
+import contextlib
 import os
 import subprocess
 import sys
@@ -17,9 +18,12 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core import PackageFamily, build, build_family, \
     make_2p5d_package
+from repro.distribution import family_exec as exec_mod
 from repro.distribution.family_exec import FamilyExecutor
 
 multi_device = pytest.mark.skipif(
@@ -96,8 +100,8 @@ def test_chunked_steady_matches_unchunked(fam):
         t_ref = np.asarray(one_call.observe_batch(
             one_call.steady_state_batch(params, q), params))
         th = chunked.steady_state_batch(params, q)
-        # streamed results land on the host — that is the memory bound
-        assert isinstance(th, np.ndarray) and th.shape == (7, 564)
+        # a streamed output that fits the device stays there, joined
+        assert isinstance(th, jax.Array) and th.shape == (7, 564)
         t_chunk = np.asarray(chunked.observe_batch(th, params))
         m = build(fam.instantiate(params[3]), "rc", dtype=jnp.float64)
         t_loop = np.asarray(m.observe(m.steady_state(q[3])))
@@ -114,9 +118,66 @@ def test_chunked_transient_matches_unchunked(fam):
         chunked = build_family(fam, "rc", dtype=jnp.float64, chunk_size=2)
         o_ref = np.asarray(one_call.simulate_family(params, q, dt))
         o_chunk = chunked.simulate_family(params, q, dt)
-        assert isinstance(o_chunk, np.ndarray)
+        assert isinstance(o_chunk, jax.Array)   # fits: joined on device
         assert o_chunk.shape == (T, 5, 16)
-    assert np.abs(o_chunk - o_ref).max() < 1e-6
+    assert np.abs(np.asarray(o_chunk) - o_ref).max() < 1e-6
+
+
+def test_resident_and_landed_streams_are_bit_identical(fam, monkeypatch):
+    """The fit rule moves where a streamed output lives, not what it is:
+    B=7 over chunk_size=2 (a pad row in the last chunk) gives the same
+    bits for the steady states, their CG stats and the observation
+    whether the output stays on the device or lands chunk by chunk."""
+    params = np.vstack([fam.base_params(), fam.sample_params(6, seed=8)])
+    q = np.full((7, 16), 3.0)
+    sim = build_family(fam, "rc", chunk_size=2)
+    got = {}
+    for name, budget in (("resident", 1 << 40), ("landed", 8)):
+        monkeypatch.setattr(FamilyExecutor, "_budget",
+                            lambda self, budget=budget: budget)
+        th = sim.steady_state_batch(params, q)
+        stats = sim.last_cg_stats
+        temps = sim.observe_batch(th, params)
+        kind = jax.Array if name == "resident" else np.ndarray
+        assert isinstance(th, kind) and isinstance(temps, kind), name
+        got[name] = [np.asarray(a) for a in
+                     (th, temps, stats.iterations, stats.residual,
+                      stats.converged)]
+    for a, b in zip(got["resident"], got["landed"]):
+        np.testing.assert_array_equal(a, b)
+    assert got["resident"][0].shape == (7, 564)
+
+
+def test_over_budget_stream_lands_per_chunk(monkeypatch):
+    """The fit rule's threshold: a padded output of exactly the budget's
+    bytes per device stays on the device; one byte over the budget and
+    it lands on the host, chunk by chunk, and returns numpy."""
+    ex = FamilyExecutor(chunk_size=2)
+    x = np.arange(21, dtype=np.float32).reshape(7, 3)
+
+    def fn(x):
+        return x * 2, x.sum(axis=1)
+
+    padded_bytes = 8 * 3 * 4 + 8 * 4      # B padded 7 -> 8, both leaves
+    spans = []
+
+    def record(name, **args):
+        spans.append((name, args))
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(exec_mod, "span", record)
+    for budget, kind, resident in ((padded_bytes, jax.Array, 1),
+                                   (padded_bytes - 1, np.ndarray, 0)):
+        monkeypatch.setattr(FamilyExecutor, "_budget",
+                            lambda self, budget=budget: budget)
+        spans.clear()
+        y, s = ex.run("f", fn, (x,), in_axes=(0,))
+        assert isinstance(y, kind) and isinstance(s, kind), budget
+        assert spans[0] == ("exec.run", {"chunks": 4,
+                                         "resident": resident})
+        assert [n for n, _ in spans].count("exec.land") == 4 * (1 - resident)
+        np.testing.assert_array_equal(np.asarray(y), x * 2)
+        np.testing.assert_array_equal(np.asarray(s), x.sum(axis=1))
 
 
 def test_executor_pad_rows_are_template_candidates(fam):
@@ -190,7 +251,8 @@ def test_mesh_rom_steady_matches_vmap(fam):
 def test_mesh_composes_with_chunk_streaming(fam):
     """chunk_size rides on top of the mesh: every chunk splits over the
     shards (per-shard coo_matvec plans, no cross-device edges) and the
-    stream lands on the host chunk by chunk."""
+    joined stream stays on the devices, batch-sharded, equal to the
+    one-call path."""
     params = fam.sample_params(40, seed=7)
     q = np.full((40, 16), 3.0)
     with jax.enable_x64(True):
@@ -200,7 +262,13 @@ def test_mesh_composes_with_chunk_streaming(fam):
         t_ref = np.asarray(ref.observe_batch(
             ref.steady_state_batch(params, q), params))
         th = sim.steady_state_batch(params, q)
-        assert isinstance(th, np.ndarray)
+        # the joined states stay on the eight devices, batch-sharded
+        assert isinstance(th, jax.Array) and th.shape == (40, 564)
+        assert th.sharding.is_equivalent_to(
+            NamedSharding(sim.exec.mesh, P("data")), th.ndim)
+        assert len(th.sharding.device_set) == 8
+        th_ref = np.asarray(ref.steady_state_batch(params, q))
+        assert np.abs(np.asarray(th) - th_ref).max() < 1e-6
         t = np.asarray(sim.observe_batch(th, params))
     assert np.abs(t - t_ref).max() < 1e-6
 

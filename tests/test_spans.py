@@ -90,10 +90,8 @@ def _events(path):
     return threads
 
 
-@pytest.fixture(scope="module")
-def traced(tmp_path_factory):
+def _trace(where):
     go = _calls()
-    where = tmp_path_factory.mktemp("trace")
     jax.profiler.start_trace(str(where))
     try:
         go()
@@ -101,6 +99,11 @@ def traced(tmp_path_factory):
         jax.profiler.stop_trace()
     path, = glob.glob(str(where / "**" / "*.xplane.pb"), recursive=True)
     return _events(path)
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    return _trace(tmp_path_factory.mktemp("trace"))
 
 
 def _named(threads, name):
@@ -146,22 +149,34 @@ def test_oracle_spans_nest_with_their_counters(traced):
             ["oracle.batch"]
 
 
-def test_executor_spans_one_land_per_chunk(traced):
+@pytest.mark.parametrize("resident", [1, 0])
+def test_executor_spans_one_land_per_chunk(resident, traced, tmp_path,
+                                           monkeypatch):
+    """Two chunks a call. An output that fits stays on the device: no
+    land. One over the memory budget lands once per chunk, after its
+    dispatch. ``exec.run`` says which, and how many chunks."""
+    if not resident:
+        monkeypatch.setattr(exec_mod.FamilyExecutor, "_budget",
+                            lambda self: 8)
+        traced = _trace(tmp_path)
     runs = _named(traced, "exec.run")
-    assert [_parent(t, i) for t, i in runs] == ["rc.steady", "rc.observe"]
+    assert [(_parent(t, i), t[i]["args"]) for t, i in runs] == [
+        (p, {"chunks": 2, "resident": resident})
+        for p in ("rc.steady", "rc.observe")]
     for name in ("exec.pad", "exec.concat"):
         assert [_parent(t, i) for t, i in _named(traced, name)] == \
             ["exec.run"] * 2
     assert [(_parent(t, i), t[i]["args"]) for t, i in
             _named(traced, "exec.dispatch")] == [
         ("exec.run", {"first": 1}), ("exec.run", {"first": 0})] * 2
-    # two chunks a run: each lands once, after its dispatch
     lands = _named(traced, "exec.land")
-    assert [_parent(t, i) for t, i in lands] == ["exec.run"] * 4
+    assert [_parent(t, i) for t, i in lands] == \
+        ["exec.run"] * 4 * (1 - resident)
     order = sorted(_named(traced, "exec.dispatch") + lands,
                    key=lambda ti: ti[0][ti[1]]["start"])
-    assert [t[i]["name"] for t, i in order] == \
-        ["mfit.exec.dispatch", "mfit.exec.land"] * 4
+    assert [t[i]["name"] for t, i in order] == (
+        ["mfit.exec.dispatch"] * 4 if resident
+        else ["mfit.exec.dispatch", "mfit.exec.land"] * 4)
     assert [_parent(t, i) for t, i in _named(traced, "rc.check")] == \
         ["rc.steady"]
 

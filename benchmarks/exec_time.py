@@ -20,13 +20,11 @@ Measures, per system size and per registered fidelity:
     systems, plus the measured steady crossover that ``solver="auto"``
     keys on (with a calibration warning when the constant drifts >2x
     from the measurement);
-  * the ``fused_cg`` section (PR 6): the fused Pallas CG-step kernel —
-    per-iteration and end-to-end steady/transient times for
-    ``cg_impl="fused"`` (one launch per CG iteration) vs ``"unfused"``
-    (the historical segment-sum composition) vs the dense tier, with the
-    per-solve iteration counts / final residuals / converged flags the
-    solver now reports, and the refreshed dense-vs-CG steady crossover
-    measured on the fused path;
+  * the ``fused_cg`` section: the fused CG solve —
+    per-iteration and end-to-end steady/transient times of the CG tier
+    vs the dense tier, with the per-solve iteration counts / final
+    residuals / converged flags the solver reports, and the refreshed
+    dense-vs-CG steady crossover measured on it;
   * the ``rom`` section: the Krylov moment-matching ROM rung — basis
     construction cost, reduction ratio N/r, per-step transient time vs
     the dense tier (the node-count-independent headline) and max
@@ -379,8 +377,7 @@ def bench_sparse_solver(system: str, n_steps: int = 50) -> dict:
         out["edges"] = int(m.net.rows.size)
         out[f"steady_{tier}_s"] = _time(
             lambda m=m: m.observe(m.steady_state(q)))
-        if tier == "cg":  # the drift warning below compares THIS impl
-            out["cg_impl"] = m.cg_impl
+        if tier == "cg":
             st = m.last_cg_stats
             out["steady_cg_iters"] = int(np.asarray(st.iterations).max())
             out["steady_cg_residual"] = float(
@@ -408,13 +405,10 @@ def bench_sparse_solver(system: str, n_steps: int = 50) -> dict:
 
 
 def bench_fused_cg(system: str, n_steps: int = 50) -> dict:
-    """Fused CG-step kernel (PR 6 tentpole): ``cg_impl="fused"`` (one
-    launch per CG iteration — on CPU the fused-XLA ELL ``while_loop``
-    body) vs ``"unfused"`` (the historical segment-sum composition) vs
-    the dense tier, steady and transient, with the per-solve stats the
-    solver now reports. Per-iteration time divides the end-to-end solve
-    by the reported iteration count, so the A/B isolates the per-launch
-    overhead the fusion removes."""
+    """Fused CG solve (one kernel launch per CG iteration; on CPU the
+    ELL ``pcg_loop``) vs the dense tier, steady and transient, with the
+    per-solve stats the solver reports. Per-iteration time divides the
+    end-to-end solve by the reported iteration count."""
     pkg, n_src, spec = _package(system)
     dt = 0.01
     q = np.full(n_src, 3.0, np.float32)
@@ -430,39 +424,29 @@ def bench_fused_cg(system: str, n_steps: int = 50) -> dict:
         lambda: sim_d(dense.zero_state(), q_traj), warmup=1, reps=2) \
         / n_steps
 
-    for impl in ("fused", "unfused"):
-        m = build(pkg, "rc", solver="cg", cg_impl=impl)
-        out[f"steady_{impl}_s"] = _time(
-            lambda m=m: m.observe(m.steady_state(q)))
-        st = m.last_cg_stats
-        iters = int(np.asarray(st.iterations).max())
-        out[f"steady_{impl}_iters"] = iters
-        out[f"steady_{impl}_residual"] = float(np.asarray(st.residual).max())
-        out[f"steady_{impl}_converged"] = bool(
-            np.asarray(st.converged).all())
-        out[f"steady_per_iter_{impl}_us"] = \
-            out[f"steady_{impl}_s"] / max(iters, 1) * 1e6
-        sim = m.make_simulator(dt)
-        out[f"per_step_{impl}_s"] = _time(
-            lambda m=m, sim=sim: sim(m.zero_state(), q_traj),
-            warmup=1, reps=2) / n_steps
-        stt = getattr(sim, "last_stats", None)
-        step_iters = float(np.asarray(stt.iterations).mean()) \
-            if stt is not None else float("nan")
-        out[f"transient_iters_per_step_{impl}"] = step_iters
-        out[f"transient_per_iter_{impl}_us"] = \
-            out[f"per_step_{impl}_s"] / max(step_iters, 1e-12) * 1e6
+    m = build(pkg, "rc", solver="cg")
+    out["steady_fused_s"] = _time(lambda: m.observe(m.steady_state(q)))
+    st = m.last_cg_stats
+    iters = int(np.asarray(st.iterations).max())
+    out["steady_fused_iters"] = iters
+    out["steady_fused_residual"] = float(np.asarray(st.residual).max())
+    out["steady_fused_converged"] = bool(np.asarray(st.converged).all())
+    out["steady_per_iter_fused_us"] = \
+        out["steady_fused_s"] / max(iters, 1) * 1e6
+    sim = m.make_simulator(dt)
+    out["per_step_fused_s"] = _time(lambda: sim(m.zero_state(), q_traj),
+                                    warmup=1, reps=2) / n_steps
+    stt = getattr(sim, "last_stats", None)
+    step_iters = float(np.asarray(stt.iterations).mean()) \
+        if stt is not None else float("nan")
+    out["transient_iters_per_step_fused"] = step_iters
+    out["transient_per_iter_fused_us"] = \
+        out["per_step_fused_s"] / max(step_iters, 1e-12) * 1e6
 
-    out["steady_speedup_fused_vs_unfused"] = out["steady_unfused_s"] \
-        / max(out["steady_fused_s"], 1e-12)
     out["steady_speedup_cg"] = out["steady_dense_s"] \
         / max(out["steady_fused_s"], 1e-12)  # key _steady_crossover_nodes
-    out["transient_speedup_fused_vs_unfused"] = out["per_step_unfused_s"] \
-        / max(out["per_step_fused_s"], 1e-12)
     print(f"[fused_cg ] {system:9s} n={out['nodes']:5d} "
           f"steady fused={out['steady_fused_s']*1e3:7.2f}ms "
-          f"unfused={out['steady_unfused_s']*1e3:8.2f}ms "
-          f"({out['steady_speedup_fused_vs_unfused']:5.1f}x) "
           f"dense={out['steady_dense_s']*1e3:7.2f}ms "
           f"iters={out['steady_fused_iters']:4d} "
           f"per_iter={out['steady_per_iter_fused_us']:6.1f}us", flush=True)
@@ -924,8 +908,7 @@ def main(argv=None):
         print(f"sparse,{s['system']},n{s['nodes']},steady_speedup,"
               f"{s['steady_speedup_cg']:.2f}x")
     for s in fused:
-        print(f"fused_cg,{s['system']},n{s['nodes']},fused_vs_unfused,"
-              f"{s['steady_speedup_fused_vs_unfused']:.1f}x,vs_dense,"
+        print(f"fused_cg,{s['system']},n{s['nodes']},vs_dense,"
               f"{s['steady_speedup_cg']:.2f}x,iters,"
               f"{s['steady_fused_iters']}")
     for s in rom:

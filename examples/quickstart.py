@@ -92,10 +92,8 @@ print(f"\n[ROM  ] {rom.r:4d} of {rom.n_full} states "
 # networks and switches to the matrix-free CG path (no N x N matrix
 # ever built) above the measured crossover — here the 64-chiplet
 # system picks it automatically. Each CG iteration runs as ONE fused
-# kernel launch (kernels/fused_cg; cg_impl="auto" -> "fused" — pass
-# cg_impl="unfused" to build(...) for the historical one-op-per-piece
-# composition), and every solve reports iterations / final relative
-# residual / a converged flag.
+# kernel launch (kernels/fused_cg), and every solve reports iterations /
+# final relative residual / a converged flag.
 from repro.core import make_2p5d_package as _mk  # noqa: E402
 
 big = _mk(64)

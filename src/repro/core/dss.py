@@ -289,24 +289,21 @@ def discretize_rc(rc: ThermalRCModel, ts: float = 0.01,
 @register_fidelity("dss")
 def build_dss(pkg: Package, ts: float = 0.01, cap_multipliers=None,
               dtype=jnp.float32, solver: str = "dense",
-              cg_tol=None, cg_maxiter: int = 1000,
-              cg_impl: str = "auto") -> DSSModel:
+              cg_tol=None, cg_maxiter: int = 1000) -> DSSModel:
     """Registry builder: package -> RC network -> exact-ZOH DSS model.
 
     ``solver`` is the solver-tier knob: the ZOH discretization itself is
     inherently dense (``expm``), so the tier governs the steady-state
     path — "cg"/"auto" (above the crossover) solve the continuous fixed
-    point matrix-free as fused CG-step launches (``kernels/fused_cg``;
-    ``cg_impl="unfused"`` falls back to the one-op-per-piece
-    composition) instead of the host dense solve.
-    ``dtype``/``cg_tol``/``cg_maxiter``/``cg_impl`` thread through to
+    point matrix-free as fused CG-step launches (``kernels/fused_cg``)
+    instead of the host dense solve.
+    ``dtype``/``cg_tol``/``cg_maxiter`` thread through to
     that solve; its convergence stats are readable post-call on the
     retained closure (``model.steady_fn.last_stats``).
     """
     return discretize_rc(
         build_model(pkg, cap_multipliers=cap_multipliers, solver=solver,
-                    dtype=dtype, cg_tol=cg_tol, cg_maxiter=cg_maxiter,
-                    cg_impl=cg_impl),
+                    dtype=dtype, cg_tol=cg_tol, cg_maxiter=cg_maxiter),
         ts=ts, dtype=dtype)
 
 

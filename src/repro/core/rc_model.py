@@ -31,8 +31,7 @@ from ..distribution.family_exec import FamilyExecutor
 from ..kernels.coo_matvec.ops import coo_matvec, coo_plan, coo_segment_sum
 from ..kernels.fused_cg.adjoint import make_implicit_steady
 from ..kernels.fused_cg.ops import (CGStats, fused_cg_plan, fused_cg_solve,
-                                    pcg_loop, resolve_cg_impl,
-                                    warn_unconverged)
+                                    pcg_loop, warn_unconverged)
 from ..runtime import span
 from .assembly import NumericAssembly, adjacency_within, overlap_between
 from .fidelity import (register_family_fidelity, register_fidelity,
@@ -253,8 +252,9 @@ class ThermalRCModel:
                   solve; integrators as requested. Exact; right for the
                   paper's few-hundred-node networks.
       'cg'      — fully matrix-free: the dense G is never built, steady
-                  state is Jacobi-preconditioned CG on the O(E) COO
-                  matvec kernel (``kernels/coo_matvec``), and dense
+                  state is Jacobi-preconditioned CG on the O(E) fused
+                  CG step (``kernels/fused_cg``: one Pallas launch per
+                  iteration on TPU, ``pcg_loop`` elsewhere), and dense
                   integrators map to their matrix-free twin
                   (be_chol/be_lu -> be_cg, trap -> trap_cg). On f32
                   models the steady solve is wrapped in a mixed-precision
@@ -264,15 +264,6 @@ class ThermalRCModel:
       'auto'    — 'cg' at or above the measured crossover node count
                   (``fidelity.SOLVER_CROSSOVER_NODES``), else 'dense'.
 
-    cg_impl (how a CG iteration executes, orthogonal to the tier):
-      'fused'   — the whole PCG iteration (matvec, Jacobi apply,
-                  reductions, axpys) is one ``kernels/fused_cg`` step:
-                  a single Pallas launch on TPU, a single gather-only
-                  ELL ``while_loop`` body on CPU.
-      'unfused' — the historical one-op-per-piece composition
-                  (``segment_sum`` matvec), kept as the escape hatch and
-                  the benchmark A/B contrast.
-      'auto'    — 'fused' (the default everywhere).
     Every CG solve records per-solve convergence stats; see
     ``last_cg_stats`` and the ``last_stats`` attribute on the closures
     returned by :meth:`make_steady_solver` / :meth:`make_simulator`.
@@ -283,8 +274,8 @@ class ThermalRCModel:
     def __init__(self, net: RCNetwork, dtype=jnp.float32,
                  method: str = "be_chol", solver: str = "dense",
                  cg_tol: Optional[float] = None, cg_maxiter: int = 1000,
-                 matvec_backend: str = "auto", cg_impl: str = "auto",
-                 refine_rtol: float = 1e-9, refine_passes: int = 4):
+                 matvec_backend: str = "auto", refine_rtol: float = 1e-9,
+                 refine_passes: int = 4):
         self.net = net
         self.dtype = dtype
         self.solver = resolve_solver(solver, net.n)
@@ -302,7 +293,6 @@ class ThermalRCModel:
         # even on the dense tier)
         self._plan = coo_plan(net.rows, net.cols, net.n)
         self._backend = matvec_backend
-        self.cg_impl = resolve_cg_impl(cg_impl)
         self._gvals = jnp.asarray(net.gvals, dtype)
         self._gdiag = jnp.asarray(-net.neg_g_diag(), dtype)
         self._fused_plan_cache = None  # fused-CG plan, built lazily
@@ -369,7 +359,7 @@ class ThermalRCModel:
         exposed so callers can lower it and inspect the program.
         """
         gvals, gdiag = self._gvals, self._gdiag
-        dtype, backend, impl = self.dtype, self._backend, self.cg_impl
+        dtype, backend = self.dtype, self._backend
         tol, maxiter = self.cg_tol, self.cg_maxiter
         neg_diag = -gdiag
         plan_f = self._fused_plan
@@ -378,7 +368,7 @@ class ThermalRCModel:
         def solve_dev(rhs):  # (-G) x = rhs by Jacobi-PCG, device dtype
             return fused_cg_solve(plan_f, neg_diag, gvals, rhs,
                                   tol=tol, maxiter=maxiter,
-                                  impl=impl, backend=backend)
+                                  backend=backend)
 
         p_dev = self.P
 
@@ -480,14 +470,14 @@ class ThermalRCModel:
             cdt = C / dt
             diag = cdt - self._gdiag
             plan_f, gvals = self._fused_plan, self._gvals
-            impl, backend = self.cg_impl, self._backend
+            backend = self._backend
             tol = min(self.cg_tol, 1e-8)
 
             def step_stats(theta, q):
                 rhs = cdt * theta + P @ q
                 return fused_cg_solve(plan_f, diag, gvals, rhs, x0=theta,
                                       tol=tol, maxiter=200,
-                                      impl=impl, backend=backend)
+                                      backend=backend)
 
             def step(theta, q):
                 return step_stats(theta, q)[0]
@@ -515,7 +505,7 @@ class ThermalRCModel:
             diag = cdt - 0.5 * self._gdiag
             plan_f = self._fused_plan
             gvals_half = 0.5 * self._gvals
-            impl, backend = self.cg_impl, self._backend
+            backend = self._backend
             gm = self._gmatvec
             tol = min(self.cg_tol, 1e-8)
 
@@ -523,7 +513,7 @@ class ThermalRCModel:
                 rhs = cdt * theta + 0.5 * gm(theta) + P @ q
                 return fused_cg_solve(plan_f, diag, gvals_half, rhs,
                                       x0=theta, tol=tol, maxiter=200,
-                                      impl=impl, backend=backend)
+                                      backend=backend)
 
             def step(theta, q):
                 return step_stats(theta, q)[0]
@@ -646,42 +636,27 @@ def _resolve_cap_multipliers(pkg: Package,
 def build_model(pkg: Package, cap_multipliers: Optional[dict] = None,
                 dtype=jnp.float32, method: str = "be_chol",
                 solver: str = "dense", cg_tol: Optional[float] = None,
-                cg_maxiter: int = 1000, cg_impl: str = "auto",
-                refine_rtol: float = 1e-9, refine_passes: int = 4,
+                cg_maxiter: int = 1000, refine_rtol: float = 1e-9,
+                refine_passes: int = 4,
                 grid: Optional[NodeGrid] = None) -> ThermalRCModel:
     """Registry builder. ``cap_multipliers=None`` applies the tuned
     per-layer defaults for the package's layer stack (override with an
     explicit dict, or pass ``{}`` for the untuned network). ``solver``
-    selects the solver tier, ``cg_impl`` how its CG iterations execute
-    ("fused" single-launch kernel vs "unfused" escape hatch), and
-    ``refine_rtol``/``refine_passes`` the mixed-precision refinement of
-    its f32 cg steady solve (``refine_passes=0`` opts out; see
+    selects the solver tier and ``refine_rtol``/``refine_passes`` the
+    mixed-precision refinement of its f32 cg steady solve (``refine_passes=0`` opts out; see
     :class:`ThermalRCModel`)."""
     return ThermalRCModel(
         build_network(pkg, grid=grid,
                       cap_multipliers=_resolve_cap_multipliers(
                           pkg, cap_multipliers)),
         dtype=dtype, method=method, solver=solver, cg_tol=cg_tol,
-        cg_maxiter=cg_maxiter, cg_impl=cg_impl, refine_rtol=refine_rtol,
+        cg_maxiter=cg_maxiter, refine_rtol=refine_rtol,
         refine_passes=refine_passes)
 
 
 # ---------------------------------------------------------------------------
 # Batched design-space model: one family, many packages per device call
 # ---------------------------------------------------------------------------
-def _batched_pcg(matvec, prec, rhs, x0, tol: float, maxiter: int):
-    """Masked batched preconditioned CG on SPD systems ``A x = rhs``.
-
-    Back-compat wrapper around :func:`repro.kernels.fused_cg.ops.pcg_loop`
-    (the generic callable-matvec loop, which also returns per-row
-    :class:`CGStats`); kept because external consumers (``core/rom.py``)
-    import the x-only form. ``matvec``/``prec`` map (B, N) -> (B, N);
-    batch rows converge independently against a RELATIVE residual ``tol``
-    and are frozen (masked updates) while the rest iterate.
-    """
-    return pcg_loop(matvec, prec, rhs, x0, tol, maxiter)[0]
-
-
 class RCFamilyModel:
     """Thermal RC model over a :class:`~repro.core.family.PackageFamily`.
 
@@ -721,8 +696,7 @@ class RCFamilyModel:
 
     def __init__(self, family, cap_multipliers: Optional[dict] = None,
                  dtype=jnp.float32, cg_tol: Optional[float] = None,
-                 cg_maxiter: int = 150, solver: str = "dense",
-                 cg_impl: str = "auto", mesh=None,
+                 cg_maxiter: int = 150, solver: str = "dense", mesh=None,
                  chunk_size: Optional[int] = None,
                  executor: Optional[FamilyExecutor] = None):
         self.family = family
@@ -744,7 +718,6 @@ class RCFamilyModel:
             (1e-9 if dtype == jnp.float64 else 1e-6)
         self.cg_maxiter = cg_maxiter
         self.solver = resolve_solver(solver, family.sym.n)
-        self.cg_impl = resolve_cg_impl(cg_impl)
         self._fused_plan_cache = None
         self._implicit_steady_cache = None
         self.last_cg_stats: Optional[CGStats] = None
@@ -853,7 +826,6 @@ class RCFamilyModel:
             return fused_cg_solve(self._fused_plan, diag, gvals, rhs,
                                   x0=x0, tol=self.cg_tol,
                                   maxiter=max(self.cg_maxiter, 1000),
-                                  impl=self.cg_impl,
                                   backend=num.matvec_backend)
 
         def matvec(x):
@@ -929,7 +901,7 @@ class RCFamilyModel:
         if self._implicit_steady_cache is None:
             self._implicit_steady_cache = make_implicit_steady(
                 self._fused_plan, tol=self.cg_tol,
-                maxiter=max(self.cg_maxiter, 1000), impl=self.cg_impl,
+                maxiter=max(self.cg_maxiter, 1000),
                 backend=self.num.matvec_backend,
                 site="rc family peak_steady adjoint CG")
         return self._implicit_steady_cache
@@ -1056,7 +1028,7 @@ class RCFamilyModel:
         steady solves are where convergence is observable."""
         num = self.num
         tol, maxiter = self.cg_tol, self.cg_maxiter
-        impl, backend = self.cg_impl, num.matvec_backend
+        backend = num.matvec_backend
         plan_f = self._fused_plan
 
         def simulate(params, q_traj):
@@ -1077,7 +1049,7 @@ class RCFamilyModel:
                     qt.astype(self.dtype) * scale[:, None])
                 th, _ = fused_cg_solve(plan_f, mdiag, gvals, rhs, x0=th,
                                        tol=tol, maxiter=maxiter,
-                                       impl=impl, backend=backend)
+                                       backend=backend)
                 return th, jnp.einsum("bon,bn->bo", h, th)
 
             th0 = jnp.zeros((params.shape[0], self.n), self.dtype)

@@ -41,8 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .ops import (CGStats, FusedCGPlan, _offdiag_segsum, fused_cg_solve,
-                  warn_unconverged)
+from .ops import CGStats, FusedCGPlan, fused_cg_solve, warn_unconverged
 
 __all__ = [
     "adjoint_offdiag_matvec", "last_stats", "make_implicit_steady",
@@ -115,13 +114,16 @@ def adjoint_offdiag_matvec(plan: FusedCGPlan, gvals, x):
     """
     if plan.n_edges == 0:
         return jnp.zeros_like(x)
-    out = _offdiag_segsum(plan, gvals[..., plan.edge_perm],
-                          x[..., plan.node_perm])
-    return out[..., plan.node_inv]
+    contrib = (gvals[..., plan.edge_perm]
+               * x[..., plan.node_perm][..., plan.cols_sorted])
+    out = jax.ops.segment_sum(jnp.moveaxis(contrib, -1, 0),
+                              plan.rows_sorted, num_segments=plan.n,
+                              indices_are_sorted=True)
+    return jnp.moveaxis(out, 0, -1)[..., plan.node_inv]
 
 
 def make_implicit_steady(plan: FusedCGPlan, *, tol: float, maxiter: int,
-                         impl: str = "auto", backend: str = "auto",
+                         backend: str = "auto",
                          site: str = "implicit steady adjoint CG"):
     """Build a reverse-differentiable matrix-free steady solver.
 
@@ -146,7 +148,7 @@ def make_implicit_steady(plan: FusedCGPlan, *, tol: float, maxiter: int,
 
     def _solve(diag, gvals, rhs):
         return fused_cg_solve(plan, diag, gvals, rhs, tol=tol,
-                              maxiter=maxiter, impl=impl, backend=backend)
+                              maxiter=maxiter, backend=backend)
 
     @jax.custom_vjp
     def solve(diag, gvals, rhs):

@@ -2,9 +2,9 @@
 
 The matrix-free solver tier (``solver="cg"``) spends its life in the CG
 body: an off-diagonal COO matvec, a Jacobi preconditioner apply, two
-reductions (``p·Ap``, ``r·z``) and three axpys. Unfused, every one of
-those is a separate XLA op — and on the target hardware a separate
-dispatch — per iteration. This kernel executes the WHOLE iteration in a
+reductions (``p·Ap``, ``r·z``) and three axpys. In the XLA form
+(``ops.pcg_loop``) every one of those is a separate op — and on the
+target hardware a separate dispatch — per iteration. This kernel executes the WHOLE iteration in a
 single launch, flash-attention style (see ``kernels/flash_attn``):
 
   * grid = (batch blocks, edge tiles); the edge dimension is sequential
@@ -34,19 +34,19 @@ single launch, flash-attention style (see ``kernels/flash_attn``):
     iterations;
   * the batch axis rides the GEMM sublane dimension as in ``coo_matvec``,
     so the family solvers need no vmap, and per-row live masks replicate
-    the masked-batch semantics of the unfused loop bit for bit. The
+    the masked-batch semantics of ``ops.pcg_loop`` bit for bit. The
     batch block is sized to the batch and to VMEM (``ops.fused_cg_block``):
     the one-hot tiles are built once per grid step and the MXU loads each
     weight tile once per step, so a taller block serves more rows with
     both.
 
-The masking formulas are EXACTLY those of the unfused reference loop
+The masking formulas are EXACTLY those of the XLA form's loop
 (``ops.pcg_loop``): a row is live while ``||r||^2 > tol^2 ||b||^2``;
 frozen rows get alpha = beta = 0 and coast unchanged. Padded lanes carry
 ``diag = 1`` and zero state so the Jacobi apply never divides 0/0.
 
 ``ops.py`` owns planning (RCM ordering, edge sort, window measurement,
-ELL arrays for the fused-XLA fallback) and the solver driver; ``ref.py``
+ELL arrays for the XLA form) and the solver, ``fused_cg_solve``; ``ref.py``
 is the dense oracle.
 """
 from __future__ import annotations

@@ -4,28 +4,23 @@
 Cuthill-McKee node reordering (bounds the per-tile column window the
 kernel gathers from), a row sort of the edges in the permuted space,
 per-tile window measurement, and the ELL (padded row-major) arrays the
-fused-XLA fallback uses for a gather-only matvec on CPU.
+XLA form uses for a gather-only matvec.
 
 ``fused_cg_solve`` is the solver: batched Jacobi-preconditioned CG on
-``A = diag(diag) - offdiag(gvals)`` with the EXACT masked-row semantics
-of the historical ``_batched_pcg`` loop in ``core/rc_model.py``, plus
-per-row convergence stats (``CGStats``). Three implementations share it:
+``A = diag(diag) - offdiag(gvals)`` with per-row live masks and
+convergence stats (``CGStats``). It is one masked loop in two forms:
 
-  * ``impl="fused"``, backend "pallas"/"interpret" — the outer
-    ``while_loop`` body is ONE ``kernel.fused_cg_step_pallas`` launch;
-  * ``impl="fused"``, backend "xla" — one fused XLA ``while_loop`` whose
-    matvec is the gather-only ELL form (no scatter, no segment-sum);
-    this is the CPU/CI default and is itself far faster than the
-    historical composition;
-  * ``impl="unfused"`` — the historical one-op-per-piece loop
-    (``jax.ops.segment_sum`` matvec), kept as the A/B contrast and
-    escape hatch.
+  * backend "pallas"/"interpret" — the outer ``while_loop`` body is ONE
+    ``kernel.fused_cg_step_pallas`` launch;
+  * backend "xla" — ``pcg_loop`` with the gather-only ELL matvec (no
+    scatter, no segment-sum) and the Jacobi apply ``r / diag``; the CPU
+    form and every f64 solve.
 
-``pcg_loop`` is the generic masked PCG loop with callable matvec /
-preconditioner (used by the dense-tier family solver with its template
-Cholesky preconditioner); it returns the same ``CGStats``.
+``pcg_loop`` is that masked PCG loop with a callable matvec and
+preconditioner; the family dense tier (template Cholesky) and the FVM
+stencil solve call it too.
 
-NOTE: the fused paths are built on ``lax.while_loop``, so reverse-mode
+NOTE: both forms are built on ``lax.while_loop``, so reverse-mode
 AD cannot unroll them directly. STEADY solves are differentiable anyway
 via the implicit-function-theorem wrapper in ``adjoint.py``
 (:func:`repro.kernels.fused_cg.adjoint.make_implicit_steady`): the
@@ -46,7 +41,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..coo_matvec.kernel import coo_segment_sum_sorted
 from ..backend import resolve_backend
 from ..coo_matvec.ops import _round_up
 from .kernel import LANE, SUBLANE, fused_cg_step_pallas
@@ -55,11 +49,8 @@ __all__ = [
     "CGStats", "FusedCGPlan", "all_finite", "fallback_counts",
     "fused_cg_block", "fused_cg_plan", "fused_cg_solve",
     "fused_cg_vmem_bytes", "pcg_loop", "record_fallback",
-    "resolve_cg_impl", "warn_unconverged", "unconverged_counts",
-    "reset_unconverged_counts",
+    "warn_unconverged", "unconverged_counts", "reset_unconverged_counts",
 ]
-
-_CG_IMPLS = ("auto", "fused", "unfused")
 
 
 class CGStats(NamedTuple):
@@ -72,14 +63,6 @@ class CGStats(NamedTuple):
     iterations: Any
     residual: Any
     converged: Any
-
-
-def resolve_cg_impl(impl: str) -> str:
-    """'auto' -> 'fused' (every backend has a fused form: the Pallas
-    kernel on TPU, the ELL while_loop on CPU); validate otherwise."""
-    if impl not in _CG_IMPLS:
-        raise ValueError(f"cg_impl must be one of {_CG_IMPLS}, got {impl!r}")
-    return "fused" if impl == "auto" else impl
 
 
 def _rcm_order(rows: np.ndarray, cols: np.ndarray, n: int) -> np.ndarray:
@@ -111,7 +94,7 @@ class FusedCGPlan:
     ``edge_perm`` gathers original-order edge values into sorted order.
     ``rows2d`` holds ABSOLUTE sorted rows, ``cols2d`` holds columns
     RELATIVE to the owning tile's lane-aligned ``col_base``. The ELL
-    arrays give the scatter-free matvec for the fused-XLA fallback:
+    arrays give the scatter-free matvec of the XLA form:
     ``offdiag(x) = sum_k (gvals[..., ell_src] * ell_mask) * x[..., ell_cols]``.
     """
     n: int
@@ -268,161 +251,102 @@ def fused_cg_block(b: int, plan: FusedCGPlan, itemsize: int) -> tuple:
     return block, min(max(need, _VMEM_FLOOR), _VMEM_CEIL)
 
 
-# --------------------------------------------------------------------------
-# matvec forms (all in the plan's permuted node space)
-
-def _offdiag_ell(plan: FusedCGPlan, gv_ell: jnp.ndarray,
-                 x: jnp.ndarray) -> jnp.ndarray:
-    """Gather-only ELL matvec: gv_ell (..., N, K) pre-masked values."""
-    return jnp.sum(gv_ell * x[..., plan.ell_cols], axis=-1)
-
-
-def _offdiag_segsum(plan: FusedCGPlan, gv_sorted: jnp.ndarray,
-                    x: jnp.ndarray) -> jnp.ndarray:
-    """Historical composition: gather + ``jax.ops.segment_sum``."""
-    if plan.n_edges == 0:
-        return jnp.zeros_like(x)
-    contrib = gv_sorted * x[..., plan.cols_sorted]
-    flat = jnp.moveaxis(contrib, -1, 0)
-    out = jax.ops.segment_sum(flat, plan.rows_sorted,
-                              num_segments=plan.n, indices_are_sorted=True)
-    return jnp.moveaxis(out, 0, -1)
+def _ell_matvec(plan: FusedCGPlan, gvals: jnp.ndarray) -> Callable:
+    """Gather-only ELL off-diagonal matvec ``x -> offdiag(gvals) x`` in the
+    plan's permuted node space: no scatter, no segment-sum. The masked
+    (..., N, K) values are formed here, once, outside any loop."""
+    gv_ell = ((gvals[..., plan.ell_src] * plan.ell_mask.astype(gvals.dtype))
+              if plan.n_edges else
+              jnp.zeros(gvals.shape[:-1] + (plan.n, 1), gvals.dtype))
+    return lambda x: jnp.sum(gv_ell * x[..., plan.ell_cols], axis=-1)
 
 
-def _offdiag_coo_kernel(plan: FusedCGPlan, gv_sorted: jnp.ndarray,
-                        x: jnp.ndarray, interpret: bool) -> jnp.ndarray:
-    """Unfused-on-device contrast: one ``coo_matvec`` kernel launch per
-    matvec (plus separate XLA ops for everything else in the CG body)."""
-    b, n = x.shape
-    contrib = gv_sorted * x[:, plan.cols_sorted]
-    b_pad = _round_up(b, SUBLANE)
-    vals = jnp.pad(contrib, ((0, b_pad - b), (0, plan.e_pad - plan.n_edges)))
-    out = coo_segment_sum_sorted(vals, plan.rows2d, n_pad=plan.n_pad,
-                                 span=plan.row_span, be=plan.block_edges,
-                                 interpret=interpret)
-    return out[:b, :n]
+def _live_threshold(rhs, tol):
+    """``(bnorm2g, tol2b)``: ||b||^2 per row (1 where b = 0) and the
+    live-row threshold tol^2 ||b||^2 on ||r||^2."""
+    bnorm2 = jnp.sum(rhs * rhs, axis=1)
+    bnorm2g = jnp.where(bnorm2 == 0, 1.0, bnorm2)
+    return bnorm2g, jnp.asarray(tol, rhs.dtype) ** 2 * bnorm2g
+
+
+def _cg_stats(itr, rn2, bnorm2g, tol2b) -> CGStats:
+    return CGStats(iterations=itr, residual=jnp.sqrt(rn2 / bnorm2g),
+                   converged=rn2 <= tol2b)
 
 
 def _solve2d(plan: FusedCGPlan, diag, gvals, rhs, x0, *, tol, maxiter,
-             impl, backend):
-    """Batched Jacobi PCG on (B, N) operands in permuted space."""
+             backend):
+    """Batched Jacobi PCG on (B, N) operands in permuted space: ``pcg_loop``
+    on the ELL matvec for backend "xla", else one fused kernel launch per
+    iteration."""
+    if backend == "xla":
+        offmv = _ell_matvec(plan, gvals)
+        return pcg_loop(lambda p: diag * p - offmv(p), lambda r: r / diag,
+                        rhs, x0, tol, maxiter)
+
     dtype = rhs.dtype
     b, n = rhs.shape
-    bnorm2 = jnp.sum(rhs * rhs, axis=1)
-    bnorm2g = jnp.where(bnorm2 == 0, 1.0, bnorm2)
-    tol2b = jnp.asarray(tol, dtype) ** 2 * bnorm2g
-
+    bnorm2g, tol2b = _live_threshold(rhs, tol)
     gv_sorted = gvals[..., plan.edge_perm]
-
-    use_pallas = impl == "fused" and backend in ("pallas", "interpret")
-    if impl == "fused":
-        # the ELL gather beats gather+segment_sum at every batch width
-        # measured on this container (35-49x at B<=8, ~1.3x at B=256)
-        gv_ell = ((gvals[..., plan.ell_src]
-                   * plan.ell_mask.astype(dtype)) if plan.n_edges else
-                  jnp.zeros(gvals.shape[:-1] + (n, 1), dtype))
-        offmv = lambda x: _offdiag_ell(plan, gv_ell, x)
-    elif backend in ("pallas", "interpret"):
-        offmv = lambda x: _offdiag_coo_kernel(plan, gv_sorted, x,
-                                              backend == "interpret")
-    else:
-        offmv = lambda x: _offdiag_segsum(plan, gv_sorted, x)
-
+    offmv = _ell_matvec(plan, gvals)
     r0 = rhs - (diag * x0 - offmv(x0))
     z0 = r0 / diag
     rz0 = jnp.sum(r0 * z0, axis=1)
     rn20 = jnp.sum(r0 * r0, axis=1)
     it0 = jnp.zeros((b,), jnp.int32)
 
-    if use_pallas:
-        block_b, vmem_limit = fused_cg_block(b, plan, dtype.itemsize)
-        b_pad = _round_up(b, block_b)
-        n_pad = plan.n_pad
+    block_b, vmem_limit = fused_cg_block(b, plan, dtype.itemsize)
+    b_pad = _round_up(b, block_b)
+    n_pad = plan.n_pad
 
-        def padn(a, v=0.0):
-            return jnp.pad(a, ((0, b_pad - b), (0, n_pad - n)),
-                           constant_values=v)
+    def padn(a, v=0.0):
+        return jnp.pad(a, ((0, b_pad - b), (0, n_pad - n)),
+                       constant_values=v)
 
-        def pad1(a, v=0):
-            return jnp.pad(a[:, None], ((0, b_pad - b), (0, 0)),
-                           constant_values=v)
+    def pad1(a, v=0):
+        return jnp.pad(a[:, None], ((0, b_pad - b), (0, 0)),
+                       constant_values=v)
 
-        gv_p = jnp.pad(jnp.broadcast_to(gv_sorted, (b, plan.n_edges)),
-                       ((0, b_pad - b), (0, plan.e_pad - plan.n_edges)))
-        diag_p = padn(diag, 1.0)
-        tol_p = pad1(tol2b, 1)  # padded rows never live (rn2 = 0 < 1)
+    gv_p = jnp.pad(jnp.broadcast_to(gv_sorted, (b, plan.n_edges)),
+                   ((0, b_pad - b), (0, plan.e_pad - plan.n_edges)))
+    diag_p = padn(diag, 1.0)
+    tol_p = pad1(tol2b, 1)  # padded rows never live (rn2 = 0 < 1)
 
-        def step(x, r, p, rz, rn2, itr):
-            return fused_cg_step_pallas(
-                plan.col_base, plan.rows2d, plan.cols2d, gv_p, diag_p,
-                x, r, p, rz, rn2, itr, tol_p,
-                row_span=plan.row_span, col_span=plan.col_span,
-                be=plan.block_edges, block_b=block_b,
-                vmem_limit_bytes=vmem_limit,
-                interpret=backend == "interpret")
+    def step(x, r, p, rz, rn2, itr):
+        return fused_cg_step_pallas(
+            plan.col_base, plan.rows2d, plan.cols2d, gv_p, diag_p,
+            x, r, p, rz, rn2, itr, tol_p,
+            row_span=plan.row_span, col_span=plan.col_span,
+            be=plan.block_edges, block_b=block_b,
+            vmem_limit_bytes=vmem_limit,
+            interpret=backend == "interpret")
 
-        def cond(s):
-            it, _, _, _, _, rn2, _ = s
-            return (it < maxiter) & jnp.any(rn2 > tol_p)
+    def cond(s):
+        it, _, _, _, _, rn2, _ = s
+        return (it < maxiter) & jnp.any(rn2 > tol_p)
 
-        def body(s):
-            it, x, r, p, rz, rn2, itr = s
-            x, r, p, rz, rn2, itr = step(x, r, p, rz, rn2, itr)
-            return it + 1, x, r, p, rz, rn2, itr
+    def body(s):
+        it, x, r, p, rz, rn2, itr = s
+        x, r, p, rz, rn2, itr = step(x, r, p, rz, rn2, itr)
+        return it + 1, x, r, p, rz, rn2, itr
 
-        init = (jnp.asarray(0), padn(x0), padn(r0), padn(z0),
-                pad1(rz0), pad1(rn20), pad1(it0))
-        _, x, _, _, _, rn2, itr = jax.lax.while_loop(cond, body, init)
-        x = x[:b, :n]
-        rn2 = rn2[:b, 0]
-        itr = itr[:b, 0]
-    else:
-        def matvec(p):
-            return diag * p - offmv(p)
-
-        def cond(s):
-            it, _, _, _, _, rn2, _ = s
-            return (it < maxiter) & jnp.any(rn2 > tol2b)
-
-        def body(s):
-            it, x, r, p, rz, rn2, itr = s
-            ap = matvec(p)
-            live = rn2 > tol2b
-            denom = jnp.sum(p * ap, axis=1)
-            alpha = jnp.where(live,
-                              rz / jnp.where(denom == 0, 1.0, denom), 0.0)
-            x = x + alpha[:, None] * p
-            r = r - alpha[:, None] * ap
-            z = r / diag
-            rz_new = jnp.sum(r * z, axis=1)
-            beta = jnp.where(live,
-                             rz_new / jnp.where(rz == 0, 1.0, rz), 0.0)
-            p = z + beta[:, None] * p
-            return (it + 1, x, r, p, rz_new, jnp.sum(r * r, axis=1),
-                    itr + live.astype(jnp.int32))
-
-        init = (jnp.asarray(0), x0, r0, z0, rz0, rn20, it0)
-        _, x, _, _, _, rn2, itr = jax.lax.while_loop(cond, body, init)
-
-    stats = CGStats(iterations=itr,
-                    residual=jnp.sqrt(rn2 / bnorm2g),
-                    converged=rn2 <= tol2b)
-    return x, stats
+    init = (jnp.asarray(0), padn(x0), padn(r0), padn(z0),
+            pad1(rz0), pad1(rn20), pad1(it0))
+    _, x, _, _, _, rn2, itr = jax.lax.while_loop(cond, body, init)
+    x, rn2, itr = x[:b, :n], rn2[:b, 0], itr[:b, 0]
+    return x, _cg_stats(itr, rn2, bnorm2g, tol2b)
 
 
 def fused_cg_solve(plan: FusedCGPlan, diag, gvals, rhs, x0=None, *,
-                   tol: float, maxiter: int, impl: str = "auto",
-                   backend: str = "auto"):
+                   tol: float, maxiter: int, backend: str = "auto"):
     """Solve ``(diag(diag) - offdiag(gvals)) x = rhs`` by Jacobi PCG.
 
     diag (..., N) positive; gvals (..., E) POSITIVE pairwise conductances
     (the off-diagonal magnitude being subtracted); rhs (..., N); leading
     axes broadcast. Returns ``(x, CGStats)`` with x matching the
-    broadcast leading shape. ``impl``: "auto" | "fused" | "unfused";
-    ``backend``: "auto" | "pallas" | "interpret" | "xla" (see
-    ``kernels/backend.py``).
+    broadcast leading shape. ``backend``: "auto" | "pallas" |
+    "interpret" | "xla" (see ``kernels/backend.py``).
     """
-    impl = resolve_cg_impl(impl)
     n, e = plan.n, plan.n_edges
     diag = jnp.asarray(diag)
     gvals = jnp.asarray(gvals)
@@ -446,24 +370,24 @@ def fused_cg_solve(plan: FusedCGPlan, diag, gvals, rhs, x0=None, *,
     # reshape((-1, 0)) is ill-posed, so size the empty-edge case off b2
     g2 = flat(gvals, e) if e else jnp.zeros((b2.shape[0], 0), dtype)
     xp, stats = _solve2d(plan, d2, g2, b2, x02, tol=tol, maxiter=maxiter,
-                         impl=impl, backend=backend)
+                         backend=backend)
     x = xp[:, plan.node_inv].reshape(lead + (n,))
     return x, CGStats(*(s.reshape(lead) for s in stats))
 
 
 def pcg_loop(matvec: Callable, prec: Callable, rhs, x0, tol: float,
              maxiter: int):
-    """Generic masked batched PCG with callable matvec/preconditioner.
+    """Masked batched PCG with callable matvec/preconditioner.
 
-    Operands are (B, N); per-row live masks freeze converged rows exactly
-    as the historical ``_batched_pcg``. Returns ``(x, CGStats)`` with
-    (B,)-shaped stats. Used where the preconditioner is NOT Jacobi (the
-    family dense tier's template Cholesky).
+    Operands are (B, N); a row is live while ``||r||^2 > tol^2 ||b||^2``,
+    and frozen rows get alpha = beta = 0 and coast unchanged. Returns
+    ``(x, CGStats)`` with (B,)-shaped stats. The one CG loop outside the
+    fused kernel; its callers: ``fused_cg_solve``'s XLA form (ELL matvec,
+    Jacobi), the family dense tier (``RCFamilyModel._pcg``, template
+    Cholesky) and the FVM's ``stencil_pcg``.
     """
     rhs = jnp.asarray(rhs)
-    bnorm2 = jnp.sum(rhs * rhs, axis=1)
-    bnorm2g = jnp.where(bnorm2 == 0, 1.0, bnorm2)
-    tol2b = jnp.asarray(tol, rhs.dtype) ** 2 * bnorm2g
+    bnorm2g, tol2b = _live_threshold(rhs, tol)
 
     def cond(s):
         it, _, _, _, _, rn2, _ = s
@@ -491,9 +415,7 @@ def pcg_loop(matvec: Callable, prec: Callable, rhs, x0, tol: float,
     init = (jnp.asarray(0), x0, r0, z0, jnp.sum(r0 * z0, axis=1),
             jnp.sum(r0 * r0, axis=1), jnp.zeros(rhs.shape[0], jnp.int32))
     _, x, _, _, _, rn2, itr = jax.lax.while_loop(cond, body, init)
-    return x, CGStats(iterations=itr,
-                      residual=jnp.sqrt(rn2 / bnorm2g),
-                      converged=rn2 <= tol2b)
+    return x, _cg_stats(itr, rn2, bnorm2g, tol2b)
 
 
 # Per-solve-site dedup state for warn_unconverged: a high-QPS serving

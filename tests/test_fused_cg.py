@@ -1,7 +1,8 @@
 """Parity and convergence tests for the fused CG-step kernel
-(``kernels/fused_cg``): every impl x backend pairing against the dense
-oracle, the Pallas kernel in interpret mode on CPU, stats/converged-flag
-behavior, and fused-vs-unfused agreement on random geometries."""
+(``kernels/fused_cg``): ``fused_cg_solve`` and ``pcg_loop`` on the COO
+matvec kernel, each on both backends, against the dense oracle, the
+Pallas kernels in interpret mode on CPU, stats/converged-flag behavior,
+and cg-tier-vs-dense-tier agreement on random geometries."""
 import warnings
 
 import jax
@@ -12,9 +13,10 @@ import pytest
 
 from repro.core import (ThermalRCModel, build_network, make_2p5d_package,
                         package_from_name)
+from repro.kernels.coo_matvec.ops import coo_matvec, coo_plan
 from repro.kernels.fused_cg import ops
 from repro.kernels.fused_cg.ops import (fused_cg_plan, fused_cg_solve,
-                                        pcg_loop, resolve_cg_impl)
+                                        pcg_loop)
 from repro.kernels.fused_cg.ref import dense_matrix_ref, dense_solve_ref
 
 # (n_nodes, n_edge_pairs): ragged sizes spanning sub-tile to multi-tile
@@ -22,8 +24,11 @@ from repro.kernels.fused_cg.ref import dense_matrix_ref, dense_solve_ref
 SIZES = [(17, 9), (37, 230), (129, 511), (129, 513), (300, 2048),
          (564, 5000)]
 
+# (solver, backend): ``fused_cg_solve`` on its two forms, and
+# ``pcg_loop`` on the operator of the family dense tier — the COO matvec
+# kernel in its Pallas (interpret) and XLA forms — with Jacobi
 PAIRINGS = [("fused", "interpret"), ("fused", "xla"),
-            ("unfused", "interpret"), ("unfused", "xla")]
+            ("pcg_loop", "interpret"), ("pcg_loop", "xla")]
 
 
 def random_spd_system(n, e_half, seed=0):
@@ -44,55 +49,64 @@ def random_spd_system(n, e_half, seed=0):
     return rows, cols, gvals, diag
 
 
+def solve(solver, backend, rows, cols, n, diag, gvals, rhs, *, tol,
+          maxiter, dtype=None):
+    """``(diag(diag) - offdiag(gvals)) x = rhs`` by ``fused_cg_solve`` or
+    by ``pcg_loop`` with the COO matvec and Jacobi; rhs (N,) or (B, N).
+    Returns ``(x, CGStats)`` shaped like ``fused_cg_solve``'s."""
+    diag, gvals, rhs = (jnp.asarray(a, dtype) for a in (diag, gvals, rhs))
+    if solver == "fused":
+        return fused_cg_solve(fused_cg_plan(rows, cols, n), diag, gvals,
+                              rhs, tol=tol, maxiter=maxiter,
+                              backend=backend)
+    plan = coo_plan(rows, cols, n)
+    rhs2 = rhs.reshape(-1, n)
+    x, stats = pcg_loop(
+        lambda x: diag * x - coo_matvec(plan, gvals, x, backend=backend),
+        lambda r: r / diag, rhs2, jnp.zeros_like(rhs2), tol, maxiter)
+    return (x.reshape(rhs.shape),
+            ops.CGStats(*(s.reshape(rhs.shape[:-1]) for s in stats)))
+
+
 @pytest.mark.parametrize("n,e", SIZES)
-@pytest.mark.parametrize("impl,backend", PAIRINGS)
-def test_parity_vs_dense_oracle_f64(n, e, impl, backend):
+@pytest.mark.parametrize("solver,backend", PAIRINGS)
+def test_parity_vs_dense_oracle_f64(n, e, solver, backend):
     rows, cols, gvals, diag = random_spd_system(n, e, seed=n + e)
     rhs = np.random.default_rng(1).normal(size=n)
     ref = dense_solve_ref(diag, gvals, rows, cols, rhs)
     with jax.enable_x64(True):
-        plan = fused_cg_plan(rows, cols, n)
-        x, stats = fused_cg_solve(plan, jnp.asarray(diag),
-                                  jnp.asarray(gvals), jnp.asarray(rhs),
-                                  tol=1e-12, maxiter=4 * n,
-                                  impl=impl, backend=backend)
+        x, stats = solve(solver, backend, rows, cols, n, diag, gvals, rhs,
+                         tol=1e-12, maxiter=4 * n)
         assert np.asarray(stats.converged).all()
         np.testing.assert_allclose(np.asarray(x), ref, atol=1e-8)
 
 
 @pytest.mark.parametrize("b", [1, 3, 8, 11])
-@pytest.mark.parametrize("impl,backend", PAIRINGS)
-def test_batched_rhs_parity(b, impl, backend):
+@pytest.mark.parametrize("solver,backend", PAIRINGS)
+def test_batched_rhs_parity(b, solver, backend):
     n, e = 129, 513
     rows, cols, gvals, diag = random_spd_system(n, e, seed=7)
     rhs = np.random.default_rng(2).normal(size=(b, n))
     ref = dense_solve_ref(diag, gvals, rows, cols, rhs)
     with jax.enable_x64(True):
-        plan = fused_cg_plan(rows, cols, n)
-        x, stats = fused_cg_solve(plan, jnp.asarray(diag),
-                                  jnp.asarray(gvals), jnp.asarray(rhs),
-                                  tol=1e-12, maxiter=4 * n,
-                                  impl=impl, backend=backend)
+        x, stats = solve(solver, backend, rows, cols, n, diag, gvals, rhs,
+                         tol=1e-12, maxiter=4 * n)
     assert x.shape == (b, n)
     assert np.asarray(stats.iterations).shape == (b,)
     assert np.asarray(stats.converged).all()
     np.testing.assert_allclose(np.asarray(x), ref, atol=1e-8)
 
 
-@pytest.mark.parametrize("impl,backend", PAIRINGS)
-def test_f32_parity_and_stats(impl, backend):
+@pytest.mark.parametrize("solver,backend", PAIRINGS)
+def test_f32_parity_and_stats(solver, backend):
     """f32 runs converge to the f32 residual class and report it."""
     n, e = 300, 2048
     rows, cols, gvals, diag = random_spd_system(n, e, seed=3)
     rhs = np.abs(np.random.default_rng(3).normal(size=n))
     ref = dense_solve_ref(diag, gvals, rows, cols, rhs)
-    plan = fused_cg_plan(rows, cols, n)
     tol = 1e-5
-    x, stats = fused_cg_solve(plan, jnp.asarray(diag, jnp.float32),
-                              jnp.asarray(gvals, jnp.float32),
-                              jnp.asarray(rhs, jnp.float32),
-                              tol=tol, maxiter=1000,
-                              impl=impl, backend=backend)
+    x, stats = solve(solver, backend, rows, cols, n, diag, gvals, rhs,
+                     tol=tol, maxiter=1000, dtype=jnp.float32)
     assert x.dtype == jnp.float32
     assert np.asarray(stats.converged).all()
     assert float(stats.residual) <= tol
@@ -102,38 +116,32 @@ def test_f32_parity_and_stats(impl, backend):
 
 
 def test_real_table6_pattern_matches_dense_f64():
-    """The fused kernel (interpret mode) on a real Table-6 package
-    pattern agrees with the dense f64 oracle to <=1e-6."""
+    """Every pairing (the Pallas kernels in interpret mode) on a real
+    Table-6 package pattern agrees with the dense f64 oracle to
+    <=1e-6."""
     net = build_network(make_2p5d_package(16))
     diag = net.neg_g_diag()
     q = np.full(len(net.grid.source_names), 2.0)
     rhs = net.P @ q
     ref = dense_solve_ref(diag, net.gvals, net.rows, net.cols, rhs)
     with jax.enable_x64(True):
-        plan = fused_cg_plan(net.rows, net.cols, net.n)
-        for impl, backend in PAIRINGS:
-            x, stats = fused_cg_solve(
-                plan, jnp.asarray(diag), jnp.asarray(net.gvals),
-                jnp.asarray(rhs), tol=1e-12, maxiter=5000,
-                impl=impl, backend=backend)
-            assert np.asarray(stats.converged).all(), (impl, backend)
+        for solver, backend in PAIRINGS:
+            x, stats = solve(solver, backend, net.rows, net.cols, net.n,
+                             diag, net.gvals, rhs, tol=1e-12, maxiter=5000)
+            assert np.asarray(stats.converged).all(), (solver, backend)
             assert np.abs(np.asarray(x) - ref).max() < 1e-6, \
-                (impl, backend)
+                (solver, backend)
 
 
 def test_empty_pattern_degenerates_to_diagonal_solve():
     n = 40
     diag = np.linspace(1.0, 3.0, n)
     rhs = np.random.default_rng(5).normal(size=n)
+    empty = np.zeros(0, np.int32)
     with jax.enable_x64(True):
-        plan = fused_cg_plan(np.zeros(0, np.int32), np.zeros(0, np.int32),
-                             n)
-        for impl, backend in PAIRINGS:
-            x, stats = fused_cg_solve(plan, jnp.asarray(diag),
-                                      jnp.zeros((0,), jnp.float64),
-                                      jnp.asarray(rhs), tol=1e-12,
-                                      maxiter=50, impl=impl,
-                                      backend=backend)
+        for solver, backend in PAIRINGS:
+            x, stats = solve(solver, backend, empty, empty, n, diag,
+                             np.zeros(0), rhs, tol=1e-12, maxiter=50)
             np.testing.assert_allclose(np.asarray(x), rhs / diag,
                                        atol=1e-12)
 
@@ -149,13 +157,13 @@ def test_warm_start_and_zero_rhs_rows():
         plan = fused_cg_plan(rows, cols, n)
         x, st = fused_cg_solve(plan, jnp.asarray(diag),
                                jnp.asarray(gvals), jnp.asarray(rhs),
-                               tol=1e-12, maxiter=1000, impl="fused",
+                               tol=1e-12, maxiter=1000,
                                backend="interpret")
         # warm restart from the converged answer: 0 further iterations
         x2, st2 = fused_cg_solve(plan, jnp.asarray(diag),
                                  jnp.asarray(gvals), jnp.asarray(rhs),
                                  x0=x, tol=1e-10, maxiter=1000,
-                                 impl="fused", backend="interpret")
+                                 backend="interpret")
     assert np.abs(np.asarray(x)[1]).max() == 0.0
     assert np.asarray(st.converged).all()
     assert np.asarray(st2.iterations).max() == 0
@@ -170,8 +178,7 @@ def test_maxiter_cap_sets_converged_false_and_model_warns():
     _, stats = fused_cg_solve(plan, jnp.asarray(diag, jnp.float32),
                               jnp.asarray(gvals, jnp.float32),
                               jnp.asarray(rhs, jnp.float32),
-                              tol=1e-6, maxiter=2, impl="fused",
-                              backend="xla")
+                              tol=1e-6, maxiter=2, backend="xla")
     assert not np.asarray(stats.converged).any()
     assert int(np.asarray(stats.iterations)) == 2
     # ... and the model-level steady solve surfaces it host-side
@@ -213,7 +220,7 @@ def test_pcg_loop_matches_fused_jacobi():
         xf, stf = fused_cg_solve(plan, jnp.asarray(diag),
                                  jnp.asarray(gvals), jnp.asarray(rhs),
                                  tol=1e-11, maxiter=1000,
-                                 impl="fused", backend="xla")
+                                 backend="xla")
         a = jnp.asarray(dense_matrix_ref(diag, gvals, rows, cols, n))
 
         def matvec(x):
@@ -277,7 +284,7 @@ def test_wide_block_parity_and_iterations():
         out = {backend: fused_cg_solve(plan, jnp.asarray(diag),
                                        jnp.asarray(gvals), jnp.asarray(rhs),
                                        tol=1e-12, maxiter=4 * n,
-                                       impl="fused", backend=backend)
+                                       backend=backend)
                for backend in ("interpret", "xla")}
     x, stats = out["interpret"]
     assert np.asarray(stats.converged).all()
@@ -286,16 +293,8 @@ def test_wide_block_parity_and_iterations():
                                   np.asarray(out["xla"][1].iterations))
 
 
-def test_resolve_cg_impl():
-    assert resolve_cg_impl("auto") == "fused"
-    assert resolve_cg_impl("fused") == "fused"
-    assert resolve_cg_impl("unfused") == "unfused"
-    with pytest.raises(ValueError, match="cg_impl"):
-        resolve_cg_impl("bogus")
-
-
 # --------------------------------------------------------------------------
-# hypothesis property: fused and unfused agree on random geometries
+# hypothesis property: the cg and dense tiers agree on random geometries
 # (hypothesis is a dev-only extra; this block auto-skips without it, the
 # parity tests above always run)
 # --------------------------------------------------------------------------
@@ -324,19 +323,18 @@ if _HAVE_HYPOTHESIS:
 
     @given(packages(), st.floats(0.3, 4.0))
     @settings(max_examples=8, deadline=None)
-    def test_fused_matches_unfused_on_random_geometries(pkg, p_chip):
-        """Fused and unfused CG steady observations agree <=1e-6 degC
-        on random valid geometries (f64)."""
+    def test_cg_tier_matches_dense_tier_on_random_geometries(pkg, p_chip):
+        """The cg tier's steady observations agree with the dense
+        tier's to <=1e-6 degC on random valid geometries (f64)."""
         with jax.enable_x64(True):
             net = build_network(pkg)
             temps = {}
-            for impl in ("fused", "unfused"):
-                m = ThermalRCModel(net, dtype=jnp.float64, solver="cg",
-                                   cg_impl=impl)
+            for solver in ("cg", "dense"):
+                m = ThermalRCModel(net, dtype=jnp.float64, solver=solver)
                 q = np.full(len(m.source_names), p_chip)
-                temps[impl] = np.asarray(m.observe(m.steady_state(q)))
-        assert np.abs(temps["fused"] - temps["unfused"]).max() < 1e-6
+                temps[solver] = np.asarray(m.observe(m.steady_state(q)))
+        assert np.abs(temps["cg"] - temps["dense"]).max() < 1e-6
 else:  # keep the suite honest about what was skipped
     @pytest.mark.skip(reason="property tests need the 'dev' extra")
-    def test_fused_matches_unfused_on_random_geometries():
+    def test_cg_tier_matches_dense_tier_on_random_geometries():
         pass

@@ -30,8 +30,10 @@ import numpy as np
 from ..distribution.family_exec import FamilyExecutor
 from ..kernels.coo_matvec.ops import coo_matvec, coo_plan, coo_segment_sum
 from ..kernels.fused_cg.adjoint import make_implicit_steady
-from ..kernels.fused_cg.ops import (CGStats, fused_cg_plan, fused_cg_solve,
-                                    pcg_loop, warn_unconverged)
+from ..kernels.backend import resolve_backend
+from ..kernels.fused_cg.ops import (CGStats, fused_cg_passes, fused_cg_plan,
+                                    fused_cg_solve, pcg_loop,
+                                    warn_unconverged)
 from ..runtime import span
 from .assembly import NumericAssembly, adjacency_within, overlap_between
 from .fidelity import (register_family_fidelity, register_fidelity,
@@ -851,7 +853,9 @@ class RCFamilyModel:
         :class:`CGStats`), with a host-side warning when any candidate's
         solve hit the iteration cap. Spans: ``rc.steady`` around the
         executor's spans and ``rc.check``, that warning's read of the
-        stats."""
+        stats; ``rc.steady``'s ``mxu_passes`` is the fused kernel's MXU
+        passes per one-hot GEMM (``fused_cg_passes``), 0 where no fused
+        kernel runs (the dense tier, the XLA form)."""
         def _steady(x0, params, q):
             def net(p):
                 v = self._network(p)
@@ -864,7 +868,10 @@ class RCFamilyModel:
             th, stats = self._pcg(gvals, gconv, rhs, x0)
             return (th, stats), th
 
-        with span("rc.steady"):
+        fused = self.solver == "cg" and resolve_backend(
+            self.num.matvec_backend, self.dtype) != "xla"
+        with span("rc.steady",
+                  mxu_passes=fused_cg_passes(self.dtype) if fused else 0):
             th, stats = self.exec.run(
                 f"{self._ns}:rc_steady", _steady, (params, q_src),
                 in_axes=(0, 0),

@@ -19,8 +19,16 @@ single launch, flash-attention style (see ``kernels/flash_attn``):
     shape, its per-tile start is a scalar-prefetched SMEM array (a
     (1, 1) VMEM block per tile is refused by the TPU lowering), and the
     in-tile column indices are stored relative to it;
-  * both one-hot GEMMs run at full f32 precision (``EXACT``): the MXU's
-    default would round ``p`` and the products to bf16;
+  * both one-hot GEMMs are exact, in ``ops.fused_cg_passes`` single
+    bf16 MXU passes each: the one-hot tile is exact in bf16, and an f32
+    value is exactly the sum of three bf16 parts (hi = bf16(v), mid =
+    bf16(v - hi), lo = v - hi - mid), so the parts stacked along the
+    batch rows make one GEMM that loads each one-hot weight tile once,
+    and its three f32 row blocks summed give the gather bit for bit and
+    the scatter at f32 accumulation — half the passes of ``EXACT``
+    (HIGHEST), which splits the one-hot side too. A bf16 operand is one
+    part; f64 (interpret mode only: Mosaic cannot lower it) keeps one
+    ``EXACT`` pass in f64;
   * the LAST edge tile runs the epilogue: add the diagonal term, form the
     ``p·Ap`` / ``r·z`` reductions, the masked alpha/beta, the x/r/p
     updates and the new residual norm — all on the full state resident in
@@ -64,10 +72,44 @@ from ..coo_matvec.kernel import (  # shared alignment/precision contract
 __all__ = ["LANE", "SUBLANE", "fused_cg_step_pallas"]
 
 
+def _mxu_form(dtype):
+    """``(part dtype, precision, accumulator dtype)`` of the one-hot GEMMs
+    on ``dtype`` values: bf16 parts at the MXU's single-pass precision
+    into f32, or, for f64, one pass in f64 at ``EXACT``."""
+    if dtype == jnp.float64:
+        return dtype, EXACT, dtype
+    return jnp.bfloat16, jax.lax.Precision.DEFAULT, jnp.float32
+
+
+def _onehot_dot(v, sel, dims, passes: int):
+    """``dot_general(v, sel, (dims, ((), ())))`` of a (rows, k) value block
+    and a one-hot tile ``sel`` (built in the part dtype), as ``passes``
+    parts of ``v`` that sum to it exactly: hi = bf16(v), then the bf16
+    of what is left, the last part being the rest. The parts are stacked
+    along the rows into ONE GEMM, and its row blocks summed in f32 in
+    order (hi + mid) + lo."""
+    part_t, precision, acc_t = _mxu_form(v.dtype)
+    parts = []
+    for _ in range(passes - 1):
+        hi = v.astype(part_t).astype(v.dtype)
+        parts.append(hi)
+        v = v - hi
+    parts.append(v)
+    stacked = parts[0] if passes == 1 else jnp.concatenate(parts, axis=0)
+    y = jax.lax.dot_general(stacked.astype(part_t), sel, (dims, ((), ())),
+                            precision=precision, preferred_element_type=acc_t)
+    rows = y.shape[0] // passes
+    out = y[:rows]
+    for k in range(1, passes):
+        out = out + y[k * rows:(k + 1) * rows]
+    return out
+
+
 def _cg_step_kernel(colbase_ref, rows_ref, cols_ref, gv_ref, diag_ref,
                     x_ref, r_ref, p_ref, rz_ref, rn2_ref, it_ref, tol2_ref,
                     ox_ref, or_ref, op_ref, orz_ref, orn2_ref, oit_ref,
-                    ap_ref, *, n_tiles: int, row_span: int, col_span: int):
+                    ap_ref, *, n_tiles: int, row_span: int, col_span: int,
+                    passes: int):
     """One grid step: accumulate one edge tile of ``offdiag @ p``; on the
     final tile, run the whole CG-iteration epilogue.
 
@@ -85,25 +127,22 @@ def _cg_step_kernel(colbase_ref, rows_ref, cols_ref, gv_ref, diag_ref,
 
     be = gv_ref.shape[1]
     dtype = gv_ref.dtype
-    acc_t = dtype if dtype == jnp.float64 else jnp.float32
+    part_t = _mxu_form(dtype)[0]
 
     # ---- gather p over the tile's column window (one-hot GEMM) ----------
     cbase = pl.multiple_of(colbase_ref[i], LANE)
     pwin = p_ref[:, pl.ds(cbase, col_span)]              # (bb, col_span)
     selg = (cols_ref[...] == jax.lax.broadcasted_iota(
-        jnp.int32, (be, col_span), 1)).astype(dtype)      # (be, col_span)
+        jnp.int32, (be, col_span), 1)).astype(part_t)     # (be, col_span)
     # pg[b, e] = pwin[b, cols_rel[e]]
-    pg = jax.lax.dot_general(pwin, selg, (((1,), (1,)), ((), ())),
-                             precision=EXACT,
-                             preferred_element_type=acc_t).astype(dtype)
+    pg = _onehot_dot(pwin, selg, ((1,), (1,)), passes).astype(dtype)
     contrib = gv_ref[...] * pg                           # (bb, be)
 
     # ---- scatter into the tile's row window (one-hot GEMM) --------------
     rbase = pl.multiple_of((rows_ref[0, 0] // LANE) * LANE, LANE)
     selr = (rows_ref[...] == (jax.lax.broadcasted_iota(
-        jnp.int32, (be, row_span), 1) + rbase)).astype(dtype)
-    local = jnp.dot(contrib, selr, precision=EXACT,
-                    preferred_element_type=acc_t)
+        jnp.int32, (be, row_span), 1) + rbase)).astype(part_t)
+    local = _onehot_dot(contrib, selr, ((1,), (0,)), passes)
     ap_ref[:, pl.ds(rbase, row_span)] += local.astype(ap_ref.dtype)
 
     # ---- final tile: the rest of the CG iteration -----------------------
@@ -144,9 +183,11 @@ def fused_cg_step_pallas(colbase, rows2d, cols2d, gvals, diag, x, r, p,
     gvals (b_pad, e_pad) zero-padded; diag/x/r/p (b_pad, n_pad) with
     ``diag`` one-padded; rz/rn2/tol2 (b_pad, 1); it (b_pad, 1) int32.
     ``block_b`` rows of the batch share one grid step; the caller sizes
-    it and the scoped-VMEM limit (``ops.fused_cg_block``). Returns
+    it and the scoped-VMEM limit (``ops.fused_cg_block``). The one-hot
+    GEMMs take ``ops.fused_cg_passes(x.dtype)`` MXU passes. Returns
     (x', r', p', rz', rn2', it').
     """
+    from .ops import fused_cg_passes  # ops imports this module
     b_pad, e_pad = gvals.shape
     n_pad = x.shape[1]
     assert e_pad % be == 0 and rows2d.shape == (e_pad, 1), \
@@ -180,7 +221,8 @@ def fused_cg_step_pallas(colbase, rows2d, cols2d, gvals, diag, x, r, p,
     )
     return pl.pallas_call(
         functools.partial(_cg_step_kernel, n_tiles=n_tiles,
-                          row_span=row_span, col_span=col_span),
+                          row_span=row_span, col_span=col_span,
+                          passes=fused_cg_passes(dtype)),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b_pad, n_pad), dtype),      # x'

@@ -47,7 +47,7 @@ from .kernel import LANE, SUBLANE, fused_cg_step_pallas
 
 __all__ = [
     "CGStats", "FusedCGPlan", "all_finite", "fallback_counts",
-    "fused_cg_block", "fused_cg_plan", "fused_cg_solve",
+    "fused_cg_block", "fused_cg_passes", "fused_cg_plan", "fused_cg_solve",
     "fused_cg_vmem_bytes", "pcg_loop", "record_fallback",
     "warn_unconverged", "unconverged_counts", "reset_unconverged_counts",
 ]
@@ -207,32 +207,52 @@ def _freeze_plan(n, e, block_edges, row_span, col_span, n_pad, e_pad,
     )
 
 
+def fused_cg_passes(dtype) -> int:
+    """Single-pass MXU GEMMs each one-hot GEMM of the fused kernel makes
+    on ``dtype`` values: an f32 value is exactly the sum of three bf16
+    parts; a bf16 value is one. f64 runs one pass in f64 (interpret mode
+    only: Mosaic cannot lower it)."""
+    return 3 if jnp.dtype(dtype) == jnp.float32 else 1
+
+
 # Largest batch block of the fused kernel: two passes of the v5e's
 # 128-row MXU, so every one-hot tile built and every weight load serves
 # up to 256 rows. On a v5e at B = 2048, 256 ran the kernel 2-5% faster
 # than 128 on the Table-6 sweep plans (PERF.md, section 6).
 BLOCK_CAP = 256
-# VMEM the block's buffers may take (``fused_cg_vmem_bytes``). The limit
-# handed to the compiler adds room for the epilogue's temporaries, never
-# goes below the compiler's default scoped limit (16 MiB on v5e) and stays
-# well inside the v5e core's 128 MiB.
-VMEM_BUDGET = 48 << 20
+# VMEM the block's buffers may take (``fused_cg_vmem_bytes``). With the
+# f32 kernel's stacked parts and their products, block 256 takes 53 MiB
+# on the 2p5d_64 plan; 56 MiB keeps that block (128 ran 2-5% slower).
+# The limit handed to the compiler adds room for the epilogue's
+# temporaries, never goes below the compiler's default scoped limit
+# (16 MiB on v5e) and stays well inside the v5e core's 128 MiB.
+VMEM_BUDGET = 56 << 20
 _VMEM_MARGIN = 1.5
 _VMEM_FLOOR = 16 << 20
 _VMEM_CEIL = 100 << 20
 
 
 def fused_cg_vmem_bytes(block: int, plan: FusedCGPlan, itemsize: int) -> int:
-    """VMEM bytes of one fused-CG launch at batch block ``block``: the
-    seven double-buffered (block, n_pad) state blocks (diag, x, r, p in;
-    x, r, p out), the f32 ``ap`` scratch, the double-buffered gvals tile
-    and the gather and scatter one-hot temporaries."""
+    """VMEM bytes of one fused-CG launch at batch block ``block`` on
+    float operands of ``itemsize`` bytes: the seven double-buffered
+    (block, n_pad) state blocks (diag, x, r, p in; x, r, p out), the f32
+    ``ap`` scratch, the double-buffered gvals tile, the gather and
+    scatter one-hot tiles in the part dtype (bf16; f64 for f64), and
+    each GEMM's ``fused_cg_passes`` stacked parts, (block, col_span) and
+    (block, be), before and after their cast to the part dtype, with
+    their f32 products, (block, be) and (block, row_span)."""
+    dtype = jnp.dtype(f"float{8 * itemsize}")  # float16 stands for bf16
+    passes = fused_cg_passes(dtype)
+    part = 8 if itemsize == 8 else 2
+    acc = max(itemsize, 4)
     be = plan.block_edges
     state = 7 * 2 * block * plan.n_pad * itemsize
-    ap = block * plan.n_pad * max(itemsize, 4)
+    ap = block * plan.n_pad * acc
     gvals = 2 * block * be * itemsize
-    onehots = be * (plan.col_span + plan.row_span) * itemsize
-    return state + ap + gvals + onehots
+    onehots = be * (plan.col_span + plan.row_span) * part
+    stacked = passes * block * ((plan.col_span + be) * (itemsize + part)
+                                + (be + plan.row_span) * acc)
+    return state + ap + gvals + onehots + stacked
 
 
 def fused_cg_block(b: int, plan: FusedCGPlan, itemsize: int) -> tuple:

@@ -293,6 +293,163 @@ def test_wide_block_parity_and_iterations():
                                   np.asarray(out["xla"][1].iterations))
 
 
+def test_fused_cg_passes_from_the_dtype():
+    """Three bf16 parts for an f32 value, one for a bf16 value, and one
+    f64 pass (interpret mode) for f64."""
+    assert ops.fused_cg_passes(jnp.float32) == 3
+    assert ops.fused_cg_passes(jnp.bfloat16) == 1
+    assert ops.fused_cg_passes(jnp.float64) == 1
+
+
+def _split_gather(pwin, cols_rel):
+    """The kernel's gather (``_onehot_dot`` on a bf16 one-hot tile) in a
+    Pallas launch in interpret mode: out[b, e] = pwin[b, cols_rel[e]]."""
+    from jax.experimental import pallas as pl
+    from repro.kernels.fused_cg.kernel import _onehot_dot
+
+    be, span = cols_rel.shape[0], pwin.shape[1]
+
+    def kernel(p_ref, c_ref, o_ref):
+        sel = (c_ref[...] == jax.lax.broadcasted_iota(
+            jnp.int32, (be, span), 1)).astype(jnp.bfloat16)
+        o_ref[...] = _onehot_dot(p_ref[...], sel, ((1,), (1,)),
+                                 ops.fused_cg_passes(p_ref.dtype))
+
+    return pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((pwin.shape[0], be),
+                                               jnp.float32),
+        interpret=True)(pwin, cols_rel[:, None])
+
+
+def test_split_gather_is_bit_exact_over_the_f32_range():
+    """hi + mid + lo in bf16 is every f32 value of magnitude 1e-30 to
+    1e30, either sign: the three-pass gather picks each one out bit for
+    bit, as the f32 gather does."""
+    rng = np.random.default_rng(31)
+    bb, span, be = 16, 256, 384
+    mag = 10.0 ** rng.uniform(-30, 30, (bb, span))
+    pwin = (mag * rng.choice([-1.0, 1.0], (bb, span))).astype(np.float32)
+    cols_rel = rng.integers(0, span, be).astype(np.int32)
+    out = np.asarray(_split_gather(jnp.asarray(pwin), jnp.asarray(cols_rel)))
+    want = pwin[:, cols_rel]
+    np.testing.assert_array_equal(out.view(np.uint32), want.view(np.uint32))
+    # ... and a single bf16 pass of the same values is not exact
+    one = pwin.astype(jnp.bfloat16).astype(np.float32)[:, cols_rel]
+    assert (one != want).any()
+
+
+def _highest_step(plan, gv, diag, x, r, p, rz, rn2, it, tol2):
+    """One CG iteration as the kernel computed it before the split: per
+    edge tile, the gather and scatter as f32 one-hot GEMMs at HIGHEST,
+    then the epilogue. Plain jnp on the kernel's padded operands."""
+    hi = jax.lax.Precision.HIGHEST
+    be, cs, rs = plan.block_edges, plan.col_span, plan.row_span
+    rows = np.asarray(plan.rows2d)[:, 0]
+    cols = np.asarray(plan.cols2d)[:, 0]
+    cbase = np.asarray(plan.col_base)
+    ap = jnp.zeros_like(p)
+    for t in range(plan.n_tiles):
+        sl = slice(t * be, (t + 1) * be)
+        selg = jnp.asarray(cols[sl, None] == np.arange(cs), jnp.float32)
+        pg = jnp.dot(p[:, cbase[t]:cbase[t] + cs], selg.T, precision=hi)
+        rb = rows[t * be] // ops.LANE * ops.LANE
+        selr = jnp.asarray(rows[sl, None] == np.arange(rs) + rb, jnp.float32)
+        ap = ap.at[:, rb:rb + rs].add(
+            jnp.dot(gv[:, sl] * pg, selr, precision=hi))
+    ap = diag * p - ap
+    live = rn2 > tol2
+    denom = jnp.sum(p * ap, axis=1, keepdims=True)
+    alpha = jnp.where(live, rz / jnp.where(denom == 0, 1.0, denom), 0.0)
+    x = x + alpha * p
+    r = r - alpha * ap
+    z = r / diag
+    rz_new = jnp.sum(r * z, axis=1, keepdims=True)
+    beta = jnp.where(live, rz_new / jnp.where(rz == 0, 1.0, rz), 0.0)
+    return (x, r, z + beta * p, rz_new, jnp.sum(r * r, axis=1, keepdims=True),
+            it + live.astype(jnp.int32))
+
+
+@pytest.fixture(scope="module")
+def net_2p5d_64():
+    return build_network(package_from_name("2p5d_64")[0])
+
+
+def test_split_step_matches_highest_on_2p5d_64(table6_plans, net_2p5d_64):
+    """One f32 launch of the three-pass kernel (interpret mode) on the
+    real 2p5d_64 plan matches the HIGHEST formulation to rtol 1e-6 of
+    each output's largest magnitude (``r - alpha Ap`` cancels, so an
+    element's own size is no scale)."""
+    from repro.kernels.fused_cg.kernel import fused_cg_step_pallas
+
+    net, plan = net_2p5d_64, table6_plans["2p5d_64"]
+    rng = np.random.default_rng(41)
+    b, n, n_pad = 16, net.n, plan.n_pad
+    scale = rng.uniform(0.8, 1.2, (b, 1))
+    gv = np.zeros((b, plan.e_pad), np.float32)
+    gv[:, :plan.n_edges] = net.gvals[np.asarray(plan.edge_perm)] * scale
+    diag = np.ones((b, n_pad), np.float32)
+    diag[:, :n] = net.neg_g_diag()[np.asarray(plan.node_perm)] * scale
+    r = np.zeros((b, n_pad), np.float32)
+    r[:, :n] = rng.uniform(0.0, 1.0, (b, n))
+    p = r / diag
+    state = [jnp.asarray(a) for a in (
+        gv, diag, np.zeros_like(r), r, p, np.sum(r * p, 1, keepdims=True),
+        np.sum(r * r, 1, keepdims=True), np.zeros((b, 1), np.int32),
+        np.full((b, 1), 1e-30, np.float32))]
+    got = fused_cg_step_pallas(
+        plan.col_base, plan.rows2d, plan.cols2d, *state,
+        row_span=plan.row_span, col_span=plan.col_span,
+        be=plan.block_edges, block_b=8, vmem_limit_bytes=32 << 20,
+        interpret=True)
+    want = _highest_step(plan, *state)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
+        w = np.asarray(w)
+        np.testing.assert_allclose(np.asarray(g), w, rtol=1e-6,
+                                   atol=1e-6 * np.abs(w).max())
+
+
+def test_f32_solve_on_2p5d_64_iterations_match_xla(table6_plans,
+                                                     net_2p5d_64):
+    """An f32 solve on the 2p5d_64 pattern through the three-pass kernel
+    (interpret mode) converges on every row within one iteration of the
+    XLA form, at the family's f32 tolerance."""
+    net, plan = net_2p5d_64, table6_plans["2p5d_64"]
+    rng = np.random.default_rng(43)
+    b = 8
+    scale = rng.uniform(0.8, 1.2, (b, 1))
+    diag = jnp.asarray(net.neg_g_diag() * scale, jnp.float32)
+    gvals = jnp.asarray(net.gvals * scale, jnp.float32)
+    q = rng.uniform(0.5, 3.0, (b, len(net.grid.source_names)))
+    rhs = jnp.asarray(q @ net.P.T, jnp.float32)
+    out = {backend: fused_cg_solve(plan, diag, gvals, rhs, tol=1e-6,
+                                   maxiter=1000, backend=backend)
+           for backend in ("interpret", "xla")}
+    for _, stats in out.values():
+        assert np.asarray(stats.converged).all()
+    its = {k: np.asarray(v[1].iterations) for k, v in out.items()}
+    assert (its["xla"] > 10).all()
+    assert np.abs(its["interpret"] - its["xla"]).max() <= 1, its
+
+
+def test_vmem_bytes_count_the_split(table6_plans):
+    """``fused_cg_vmem_bytes`` on the 2p5d_64 plan at block 256, f32, by
+    hand: the state, ap and gvals buffers, the bf16 one-hot tiles and the
+    three stacked parts of each GEMM (f32 before the cast, bf16 after)
+    with their f32 products."""
+    plan = table6_plans["2p5d_64"]
+    assert (plan.n_pad, plan.block_edges, plan.col_span, plan.row_span) \
+        == (2944, 512, 768, 256)
+    state = 7 * 2 * 256 * 2944 * 4
+    ap = 256 * 2944 * 4
+    gvals = 2 * 256 * 512 * 4
+    onehots = 512 * (768 + 256) * 2
+    split = 3 * 256 * ((768 + 512) * (4 + 2) + (512 + 256) * 4)
+    assert ops.fused_cg_vmem_bytes(256, plan, 4) == \
+        state + ap + gvals + onehots + split == 53 << 20
+    assert ops.fused_cg_block(2048, plan, 4) == (256, int(1.5 * (53 << 20)))
+
+
 # --------------------------------------------------------------------------
 # hypothesis property: the cg and dense tiers agree on random geometries
 # (hypothesis is a dev-only extra; this block auto-skips without it, the

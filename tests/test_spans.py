@@ -279,3 +279,26 @@ class _NoSpan:
 
 def _no_span(name, **args):
     return _NoSpan()
+
+
+@pytest.mark.parametrize("solver,backend,passes", [
+    ("cg", "interpret", 3), ("cg", "xla", 0), ("dense", "interpret", 0)])
+def test_rc_steady_counts_mxu_passes(monkeypatch, solver, backend, passes):
+    """``rc.steady`` carries the fused kernel's MXU passes a one-hot GEMM
+    (three for f32), and 0 where the steady solve runs no fused kernel:
+    the dense tier or the XLA form."""
+    seen = []
+    real = rc_mod.span
+
+    def spy(name, **args):
+        seen.append((name, args))
+        return real(name, **args)
+
+    monkeypatch.setattr(rc_mod, "span", spy)
+    pkg = make_2p5d_package(4)
+    fam = PackageFamily(pkg, params=("grid_offsets", "htc_top"))
+    sim = build_family(fam, "rc", solver=solver)
+    sim.num.matvec_backend = backend
+    sim.steady_state_batch(fam.sample_params(2, seed=3), np.full((2, 4), 2.0))
+    assert [a for n, a in seen if n == "rc.steady"] == \
+        [{"mxu_passes": passes}]
